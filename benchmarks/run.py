@@ -69,6 +69,8 @@ def main() -> None:
         import check_docs
         raise SystemExit(check_docs.main())
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    from repro.runtime import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
     print("name,us_per_call,derived")
     failed = 0
     for modname in MODULES:

@@ -535,8 +535,6 @@ class AnnEngine:
         Query tables are built once on host side and replicated;
         ``table_dtype`` selects their storage (see ``SearchConfig``).
         """
-        from jax.experimental.shard_map import shard_map
-
         store = self.store.shard(mesh, axis)
         q_codes = self.encode_queries(queries, impl=impl)
         q_words = _ops.pack_codes(q_codes, store.bits, impl=impl)
@@ -585,30 +583,30 @@ class AnnEngine:
                                                     cfg.table_dtype)
             rep = P(None, None)
             if scales is None:
-                fn = shard_map(local_fused, mesh=mesh,
-                               in_specs=(rep, rep, P(axis, None)),
-                               out_specs=(rep, rep), check_rep=False)
+                fn = jax.shard_map(local_fused, mesh=mesh,
+                                   in_specs=(rep, rep, P(axis, None)),
+                                   out_specs=(rep, rep), check_vma=False)
                 scores, ids = jax.jit(fn)(q_words, q_tables, store.words)
             else:
-                fn = shard_map(local_fused, mesh=mesh,
-                               in_specs=(rep, rep, P(axis, None), rep),
-                               out_specs=(rep, rep), check_rep=False)
+                fn = jax.shard_map(local_fused, mesh=mesh,
+                                   in_specs=(rep, rep, P(axis, None), rep),
+                                   out_specs=(rep, rep), check_vma=False)
                 scores, ids = jax.jit(fn)(q_words, q_tables, store.words,
                                           scales)
             ids = jnp.where(jnp.isneginf(scores), -1, ids)
             return ids, rho_scored(tables, ids, scores)
         if scored:
-            fn = shard_map(local_scored, mesh=mesh,
-                           in_specs=(P(None, None), P(None, None),
-                                     P(axis, None)),
-                           out_specs=(P(None, None), P(None, None)),
-                           check_rep=False)
+            fn = jax.shard_map(local_scored, mesh=mesh,
+                               in_specs=(P(None, None), P(None, None),
+                                         P(axis, None)),
+                               out_specs=(P(None, None), P(None, None)),
+                               check_vma=False)
             scores, ids = jax.jit(fn)(q_words, q_codes, store.words)
             ids = jnp.where(jnp.isneginf(scores), -1, ids)
             return ids, rho_scored(tables, ids, scores)
-        fn = shard_map(local, mesh=mesh,
-                       in_specs=(P(None, None), P(axis, None)),
-                       out_specs=(P(None, None), P(None, None)),
-                       check_rep=False)
+        fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=(P(None, None), P(axis, None)),
+                           out_specs=(P(None, None), P(None, None)),
+                           check_vma=False)
         vals, ids = jax.jit(fn)(q_words, store.words)
         return jnp.where(vals < 0, -1, ids), self._rho(vals)

@@ -33,9 +33,7 @@ _STEP_RE = re.compile(r"^step_(\d+)$")
 
 
 def _flatten(tree):
-    # jax.tree.flatten_with_path only exists on jax >= 0.4.38; the
-    # tree_util spelling works on every version we target
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    leaves, treedef = jax.tree.flatten_with_path(tree)
     return [(jax.tree_util.keystr(p), x) for p, x in leaves], treedef
 
 

@@ -242,25 +242,32 @@ def tune(op: str, run: Callable[[dict], object], dtype, dims: dict, *,
     the winner under (backend, op, bucket(dims), dtype), return it.
 
     ``run`` executes the op once with the given block kwargs (adapters
-    close over real arrays); candidates that raise (tile too large for
-    VMEM at this shape, say) are skipped. On backends where kernels run
-    in interpret mode (CPU) timing is meaningless, so without ``force``
-    or an injected ``measure`` this is a no-op returning ``{}`` — safe
-    to call unconditionally at service warmup."""
+    close over real arrays); candidates that raise (a tile the chip's
+    compiler refuses at this shape, say) are skipped, and if every
+    candidate raises, ``tune`` raises a RuntimeError naming each one and
+    its error — a family that cannot compile at all is a fault, not a
+    cold cache. On backends where kernels run in interpret mode (CPU)
+    timing is meaningless, so without ``force`` or an injected
+    ``measure`` this is a no-op returning ``{}`` — safe to call
+    unconditionally at service warmup."""
     if measure is None:
         if _backend() not in _MEASURED_BACKENDS and not force:
             return {}
         measure = lambda r, c: _default_measure(r, c, repeats)  # noqa: E731
     best, best_t = None, None
+    failures = []
     for config in candidate_configs(op):
         try:
             t = measure(run, config)
-        except Exception:
+        except Exception as e:      # noqa: BLE001 — reported below
+            failures.append(f"{config}: {type(e).__name__}: {e}")
             continue
         if best_t is None or t < best_t:
             best, best_t = config, t
     if best is None:
-        return {}
+        raise RuntimeError(
+            f"autotune {op} at {shape_bucket(**dims)}: every candidate "
+            f"failed:\n  " + "\n  ".join(failures))
     record_config(op, dtype, dims, best, cache=cache)
     return best
 

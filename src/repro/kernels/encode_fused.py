@@ -37,6 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.packing import codes_per_word
 from repro.core.schemes import CodeSpec
+from repro.kernels.pack_codes import pack_fields
 from repro.kernels.proj_code import _apply_code, _pad_to
 
 __all__ = ["encode_fused_pallas", "code_pack_pallas"]
@@ -46,18 +47,11 @@ def _code_and_pack(z, q_row, spec: CodeSpec, k: int):
     """In-register epilogue: f32 tile z [bm, kp] -> uint32 [bm, kp*b/32].
 
     Fields past the real ``k`` are forced to code 0 (the pack oracle's
-    zero padding); fields are disjoint so the bitwise-or is an integer
-    dot with the shift vector (VPU multiply-accumulate).
+    zero padding) before ``pack_codes.pack_fields`` packs the tile.
     """
-    bits = spec.bits
-    cpw = codes_per_word(bits)
-    bm, kp = z.shape
     codes = _apply_code(z, q_row, spec)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bm, kp), 1)
-    codes = jnp.where(col < k, codes, 0).astype(jnp.uint32)
-    codes = codes.reshape(bm, kp // cpw, cpw)
-    shifts = (jnp.arange(cpw, dtype=jnp.uint32) * jnp.uint32(bits))
-    return jnp.sum(codes << shifts, axis=-1, dtype=jnp.uint32)
+    col = jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+    return pack_fields(jnp.where(col < k, codes, 0), spec.bits)
 
 
 def _fused_kernel(x_ref, r_ref, q_ref, o_ref, acc_ref, *,

@@ -24,7 +24,8 @@ scratch persists across the minor axis for a fixed query tile):
 
 sweep A (j < NT)
     XOR/popcount counts per tile, accumulate A(c) for c in 0..k into a
-    [bq, k+1] VMEM histogram. At the phase boundary (j == NT) the
+    [k+1, bq] VMEM histogram (tiles are transposed, rows on sublanes and
+    queries on lanes, like every streaming kernel here). At the phase boundary (j == NT) the
     histogram inverts into (t, quota) with a min/max reduction — no
     gather, no sort.
 
@@ -37,7 +38,8 @@ sweep B (j >= NT)
     merge into the running (scores, ids) top-k exactly like
     ``packed_lut``.
 
-Scoring paths: float tables upcast to float32 at tile load and
+Scoring paths (``packed_lut.lut_scores``): float tables upcast to
+float32 before the kernel and
 accumulate in (word, field) order (bit-identical to
 ``ref.lut_scores_rowwise_ref``); int8 tables take per-(query, word)
 float32 scales, sum each word's 32/b selected entries exactly in int32,
@@ -61,11 +63,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.packing import bitmask_width
-from repro.kernels.packed_collision import (_merge_running_topk,
-                                            _mismatch_bits, _pad)
-from repro.kernels.packed_lut import _accum_lut_scores, _init_running, \
-    _lut_select
+from repro.kernels.packed_collision import (
+    _pad, _round_up, _tile_counts, float_key, init_running, key_float,
+    live_rows, mask_rows, mask_tail, merge_topk, topk_out_specs,
+    transposed_valid)
+from repro.kernels.packed_lut import (corpus_words, lut_scores,
+                                      NEG_INF_KEY, transposed_tables)
 
 __all__ = ["fused_scored_topk_pallas", "fused_scored_topk_masked_pallas"]
 
@@ -73,69 +76,38 @@ _NEG_INF = float("-inf")
 
 
 def _row_cumsum(x):
-    """Inclusive row-wise cumsum of small non-negative int32 [bq, bn]
-    via a triangular f32 matmul — one MXU op instead of a lane scan;
-    exact while row sums stay below 2^24 (tile widths are far below)."""
-    n = x.shape[-1]
+    """Inclusive cumsum down the rows of small non-negative int32
+    [bn, L] via a triangular f32 matmul — one MXU op instead of a
+    sublane scan; exact while column sums stay below 2^24 (tile heights
+    are far below)."""
+    n = x.shape[0]
     r = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    tri = (r <= c).astype(jnp.float32)
-    return jnp.dot(x.astype(jnp.float32), tri,
+    tri = (c <= r).astype(jnp.float32)
+    return jnp.dot(tri, x.astype(jnp.float32),
                    preferred_element_type=jnp.float32).astype(jnp.int32)
 
 
-def _accum_lut_scores_int8(tab, scales, words, bits: int, shape):
-    """int8-table LUT scores for a corpus tile: tab int32 [bq, F*P]
-    (upcast int8 entries), scales f32 [bq, W], words uint32 [bn, W] ->
-    f32 ``shape``. Per word: exact int32 entry sum, then one scaled
-    float32 add — the accumulation contract of
-    ``ref.lut_scores_rowwise_int8_ref``."""
-    p = 1 << bits
-    cpw = 32 // bits
-    n_words = words.shape[-1]
-    score = jnp.zeros(shape, jnp.float32)
-    for w in range(n_words):
-        word = words[:, w][None, :]                       # [1, bn]
-        isum = jnp.zeros(shape, jnp.int32)
-        for f in range(cpw):
-            c = (word >> jnp.uint32(f * bits)) & jnp.uint32(p - 1)
-            col = (w * cpw + f) * p
-            entries = [tab[:, col + i][:, None] for i in range(p)]
-            isum = isum + _lut_select(c, entries)
-        score = score + scales[:, w][:, None] * isum.astype(jnp.float32)
-    return score
-
-
 def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
-                         top_k: int, n_valid: int, block_n: int, nt: int,
+                         n_valid: int, block_n: int, nt: int,
                          has_mask: bool, has_scales: bool):
     it = iter(refs)
     q_ref, tab_ref, db_ref = next(it), next(it), next(it)
     valid_ref = next(it) if has_mask else None
     scales_ref = next(it) if has_scales else None
-    ov_ref, oi_ref = next(it), next(it)
-    above_ref, thr_ref, quota_ref, ties_ref = (next(it), next(it),
-                                               next(it), next(it))
-    vals_ref, ids_ref = next(it), next(it)
+    ok_ref, oi_ref = next(it), next(it)
+    keys_ref, score_ref, above_ref, thr_ref, quota_ref, ties_ref = (
+        next(it), next(it), next(it), next(it), next(it), next(it))
+    rk_ref, ri_ref = next(it), next(it)
 
     j = pl.program_id(1)
+    tile = jax.lax.rem(j, nt)
 
     def tile_counts():
-        q = q_ref[...]                                    # [bq, W]
-        db = db_ref[...]                                  # [bn, W]
-        xor = jnp.bitwise_xor(q[:, None, :], db[None, :, :])
-        counts = k - jnp.sum(_mismatch_bits(xor, bits), axis=-1)
-        local = jax.lax.broadcasted_iota(jnp.int32,
-                                         (counts.shape[0], block_n), 1)
-        gids = local + jax.lax.rem(j, nt) * block_n
-        counts = jnp.where(gids < n_valid, counts, -1)
+        _tile_counts(q_ref, db_ref, keys_ref, bits=bits, k=k)
+        mask_tail(keys_ref, tile * block_n, n_valid, -1)
         if has_mask:
-            v = valid_ref[...]                            # [bn/32, 1]
-            bitpos = jax.lax.broadcasted_iota(jnp.uint32,
-                                              (block_n // 32, 32), 1)
-            live = ((v >> bitpos) & jnp.uint32(1)).reshape(1, block_n)
-            counts = jnp.where(live != 0, counts, -1)
-        return counts, gids
+            mask_rows(keys_ref, live_rows(valid_ref), -1)
 
     @pl.when(j == 0)
     def _init_hist():
@@ -143,48 +115,49 @@ def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
 
     @pl.when(j < nt)
     def _sweep_a():
-        counts, _ = tile_counts()
-        cols = [jnp.sum((counts > c).astype(jnp.int32), axis=1,
-                        keepdims=True) for c in range(k + 1)]
-        above_ref[...] += jnp.concatenate(cols, axis=1)
+        tile_counts()
+
+        def bin_(c, carry):
+            above_ref[pl.ds(c, 1), :] += jnp.sum(
+                (keys_ref[...] > c).astype(jnp.int32), axis=0,
+                keepdims=True)
+            return carry
+
+        jax.lax.fori_loop(0, k + 1, bin_, 0)
 
     @pl.when(j == nt)
     def _invert():
-        a = above_ref[...]                                # [bq, k+1]
+        a = above_ref[...]                                # [k+1, bq]
         below = a < rerank_m          # nonempty: A(k) == 0 < rerank_m
-        cidx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
-        thr_ref[...] = jnp.min(jnp.where(below, cidx, k + 1), axis=1,
+        cidx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        thr_ref[...] = jnp.min(jnp.where(below, cidx, k + 1), axis=0,
                                keepdims=True)
         # A is non-increasing, so A(t) is the max over satisfied bins
-        a_t = jnp.max(jnp.where(below, a, -1), axis=1, keepdims=True)
+        a_t = jnp.max(jnp.where(below, a, -1), axis=0, keepdims=True)
         quota_ref[...] = rerank_m - a_t
         ties_ref[...] = jnp.zeros_like(ties_ref)
-        _init_running(vals_ref, ids_ref)
+        init_running(rk_ref, ri_ref, NEG_INF_KEY)
 
     @pl.when(j >= nt)
     def _sweep_b():
-        counts, gids = tile_counts()
-        t = thr_ref[...]                                  # [bq, 1]
-        is_tie = counts == t
-        tie_rank = ties_ref[...] + _row_cumsum(is_tie.astype(jnp.int32))
-        surv = (counts > t) | (is_tie & (tie_rank <= quota_ref[...]))
-        ties_ref[...] += jnp.sum(is_tie.astype(jnp.int32), axis=1,
-                                 keepdims=True)
-        db = db_ref[...]
-        if has_scales:
-            score = _accum_lut_scores_int8(
-                tab_ref[...].astype(jnp.int32), scales_ref[...], db, bits,
-                counts.shape)
-        else:
-            score = _accum_lut_scores(tab_ref[...].astype(jnp.float32), db,
-                                      bits, counts.shape)
-        score = jnp.where(surv, score, _NEG_INF)
-        _merge_running_topk(vals_ref, ids_ref, score, gids, top_k)
+        tile_counts()
+        counts = keys_ref[...]
+        t = thr_ref[...]                                  # [1, bq]
+        is_tie = (counts == t).astype(jnp.int32)
+        tie_rank = ties_ref[...] + _row_cumsum(is_tie)
+        surv = (counts > t) | ((is_tie != 0) & (tie_rank <= quota_ref[...]))
+        ties_ref[...] += jnp.sum(is_tie, axis=0, keepdims=True)
+        keys_ref[...] = surv.astype(jnp.int32)
+        lut_scores(tab_ref, corpus_words(db_ref, score_ref.shape[1]),
+                   score_ref, bits, scales_ref)
+        keys_ref[...] = jnp.where(keys_ref[...] != 0,
+                                  float_key(score_ref[...]), NEG_INF_KEY)
+        merge_topk(keys_ref, rk_ref, ri_ref, tile * block_n)
 
     @pl.when(j == 2 * nt - 1)
     def _finalize():
-        ov_ref[...] = vals_ref[...]
-        oi_ref[...] = ids_ref[...]
+        ok_ref[...] = rk_ref[...]
+        oi_ref[...] = ri_ref[...]
 
 
 def _fused_scored_call(q_words, q_tables, words_db, valid_words, scales,
@@ -205,57 +178,47 @@ def _fused_scored_call(q_words, q_tables, words_db, valid_words, scales,
     if n == 0:
         return (jnp.full((qn, top_k), _NEG_INF, jnp.float32),
                 jnp.full((qn, top_k), -1, jnp.int32))
-    qp = _pad(q_words, block_q, 0)
-    tp = _pad(q_tables, block_q, 0)
+    qT = _pad(q_words, block_q, 0).T                      # [W, Qp]
+    tT = transposed_tables(q_tables, block_q)             # [F*P, Qp]
     dbp = _pad(words_db, block_n, 0)
-    qm, nm = qp.shape[0], dbp.shape[0]
+    qm, nm = qT.shape[1], dbp.shape[0]
     nt = nm // block_n
-    inputs = [qp, tp, dbp]
+    t_rows = _round_up(top_k, 8)
+    inputs = [qT, tT, dbp]
     in_specs = [
-        pl.BlockSpec((block_q, w), lambda i, j: (i, 0)),
-        pl.BlockSpec((block_q, fp), lambda i, j: (i, 0)),
+        pl.BlockSpec((w, block_q), lambda i, j: (0, i)),
+        pl.BlockSpec((fp, block_q), lambda i, j: (0, i)),
         pl.BlockSpec((block_n, w), lambda i, j: (j % nt, 0)),
     ]
     if valid_words is not None:
-        nw = bitmask_width(n)
-        assert valid_words.shape == (nw,), (valid_words.shape, nw)
-        vw = valid_words.astype(jnp.uint32)
-        if n % 32:   # zero mask bits past N inside the last partial word
-            vw = vw.at[-1].set(vw[-1] & jnp.uint32((1 << (n % 32)) - 1))
-        vw = jnp.pad(vw, (0, nm // 32 - nw)).reshape(nm // 32, 1)
-        inputs.append(vw)
+        inputs.append(transposed_valid(valid_words, n, nm))
         in_specs.append(
             pl.BlockSpec((block_n // 32, 1), lambda i, j: (j % nt, 0)))
     if scales is not None:
-        inputs.append(_pad(scales.astype(jnp.float32), block_q, 0))
-        in_specs.append(pl.BlockSpec((block_q, w), lambda i, j: (i, 0)))
+        inputs.append(_pad(scales.astype(jnp.float32), block_q, 0).T)
+        in_specs.append(pl.BlockSpec((w, block_q), lambda i, j: (0, i)))
     kernel = functools.partial(
         _fused_scored_kernel, bits=bits, k=k, rerank_m=rerank_m,
-        top_k=top_k, n_valid=n, block_n=block_n, nt=nt,
+        n_valid=n, block_n=block_n, nt=nt,
         has_mask=valid_words is not None, has_scales=scales is not None)
-    vals, ids = pl.pallas_call(
+    out_specs, run_scratch = topk_out_specs(t_rows, block_q)
+    keys, ids = pl.pallas_call(
         kernel,
         grid=(qm // block_q, 2 * nt),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_q, top_k), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_q, top_k), lambda i, j: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qm, top_k), jnp.float32),
-            jax.ShapeDtypeStruct((qm, top_k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=[jax.ShapeDtypeStruct((t_rows, qm), jnp.int32)] * 2,
         scratch_shapes=[
-            pltpu.VMEM((block_q, k + 1), jnp.int32),
-            pltpu.VMEM((block_q, 1), jnp.int32),
-            pltpu.VMEM((block_q, 1), jnp.int32),
-            pltpu.VMEM((block_q, 1), jnp.int32),
-            pltpu.VMEM((block_q, top_k), jnp.float32),
-            pltpu.VMEM((block_q, top_k), jnp.int32),
-        ],
+            pltpu.VMEM((block_n, block_q), jnp.int32),
+            pltpu.VMEM((block_n, block_q), jnp.float32),
+            pltpu.VMEM((k + 1, block_q), jnp.int32),
+            pltpu.VMEM((1, block_q), jnp.int32),
+            pltpu.VMEM((1, block_q), jnp.int32),
+            pltpu.VMEM((1, block_q), jnp.int32),
+        ] + run_scratch,
         interpret=interpret,
     )(*inputs)
-    return vals[:qn], ids[:qn]
+    return key_float(keys[:top_k, :qn].T), ids[:top_k, :qn].T
 
 
 @functools.partial(

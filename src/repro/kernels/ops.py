@@ -1,6 +1,7 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU run the compiled kernels; elsewhere either run the
+Dispatch policy: on TPU run the compiled kernels — never the oracle or
+interpret mode unless the caller asks for it; elsewhere either run the
 kernels in interpret mode (exact semantics, used by tests) or fall back to
 the jnp oracle (fast CPU path, used by benchmarks/examples). ``impl``:
   'auto'    -> 'pallas' on TPU, 'ref' otherwise

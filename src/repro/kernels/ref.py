@@ -314,8 +314,12 @@ def packed_linear_bwd_ref(g, words_db, bits: int, *, block_c: int = 8,
     acc = jnp.zeros((g.shape[0], fp), jnp.float32)
     for lo in range(0, g.shape[1], block_n):
         hot = _onehot_rows(words_db[lo:lo + block_n], bits)
-        acc = acc + jnp.dot(g[:, lo:lo + block_n], hot,
-                            preferred_element_type=jnp.float32)
+        # the barrier keeps a compiler from folding the running sum into
+        # the matmul's accumulator (TPU XLA does under jit), which would
+        # change the order of the float additions this oracle fixes
+        acc = acc + jax.lax.optimization_barrier(
+            jnp.dot(g[:, lo:lo + block_n], hot,
+                    preferred_element_type=jnp.float32))
     return acc[:c]
 
 

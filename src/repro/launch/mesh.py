@@ -9,21 +9,10 @@ __all__ = ["make_mesh_compat", "make_production_mesh", "make_dp_mesh",
 
 
 def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    jax >= 0.5 takes ``axis_types`` (and defaults axes to Auto); 0.4.x has
-    neither ``jax.sharding.AxisType`` nor the kwarg, and its meshes are
-    implicitly Auto — so requesting Auto everywhere is the portable
-    behavior on both.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto (sharding propagation
+    decides placements, as the model code expects)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,6 +1,7 @@
 """Roofline-term derivation from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e constants):
+Three terms per (arch x shape x mesh), in seconds (the dry-run plans
+for TPU v5e, so ``roofline_terms`` defaults to the ``V5E`` peaks):
 
     compute    = HLO_FLOPs_per_device / 197e12      (bf16 MXU peak)
     memory     = HLO_bytes_per_device / 819e9       (HBM bandwidth)
@@ -24,15 +25,29 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass
 
-__all__ = ["HW", "collective_bytes", "roofline_terms", "model_flops",
-           "active_params"]
+__all__ = ["HW", "PEAKS", "V5E", "collective_bytes", "roofline_terms",
+           "model_flops", "active_params"]
 
 
 @dataclass(frozen=True)
 class HW:
-    peak_flops: float = 197e12        # bf16 / chip
-    hbm_bw: float = 819e9             # bytes/s
-    link_bw: float = 50e9             # bytes/s ICI per link
+    """Published per-chip peaks of one accelerator generation."""
+    peak_flops: float                 # bf16 FLOP/s
+    peak_int8_ops: float              # int8 OP/s
+    hbm_bw: float                     # bytes/s
+    link_bw: float                    # bytes/s ICI per link
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+# int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip interconnect per
+# chip (4 links of 50 GB/s).
+V5E = HW(peak_flops=197e12, peak_int8_ops=393e12, hbm_bw=819e9,
+         link_bw=50e9)
+
+#: peaks by ``jax.Device.device_kind``. A kind missing here has no
+#: modeled roofline — no other chip's numbers stand in for it.
+PEAKS = {"TPU v5 lite": V5E, "TPU v5e": V5E}
+
 
 
 _DTYPE_BYTES = {
@@ -124,7 +139,7 @@ def model_flops(cfg, shape_kind: str, batch: int, seq: int) -> float:
 
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
-                   coll_bytes_per_dev: float, hw: HW = HW()) -> dict:
+                   coll_bytes_per_dev: float, hw: HW = V5E) -> dict:
     t_c = flops_per_dev / hw.peak_flops
     t_m = bytes_per_dev / hw.hbm_bw
     t_l = coll_bytes_per_dev / hw.link_bw

@@ -134,6 +134,11 @@ def packed_data_grads(params, words, y_pm, fspec: PackedFeatureSpec,
     live = (None if valid_words is None
             else _packing.unpack_bitmask(valid_words, words.shape[0]))
     data_loss, g = _loss_and_margin_grad(m, y_pm, c, loss, live)
+    # without the barrier a TPU compiler fuses the bias sum into whatever
+    # computed the margins, and picks its summation order per fusion: a
+    # kernel forward and the oracle forward then give different bias
+    # gradients from bit-identical g
+    g = jax.lax.optimization_barrier(g)
     if valid_words is None:
         dt = _ops.packed_linear_bwd(g, words, fspec.bits, impl=impl)
     else:

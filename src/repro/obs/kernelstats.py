@@ -11,7 +11,8 @@ bytes-per-row accounting ``benchmarks/encode_bench.py`` used to do by
 hand, now computed once at the dispatch layer.
 
 ``roofline_table`` folds the accumulated totals against a hardware model
-(``repro.launch.roofline.HW``) into a live roofline: arithmetic
+(the ``repro.launch.roofline.PEAKS`` entry of the device's
+``device_kind``) into a live roofline: arithmetic
 intensity, modeled compute/memory time, and which wall each family sits
 against. ``tests/test_obs.py`` cross-checks the byte models against the
 actual array shapes the ``kernels/ref.py`` oracles consume and produce.
@@ -174,11 +175,16 @@ class KernelStats:
         (FLOPs/byte), modeled ``t_compute_s`` / ``t_memory_s``, the
         binding wall (``bound``), the modeled wall time ``t_model_s``
         (max of the two) and modeled ``elements_per_s`` at that wall.
-        ``hw`` defaults to ``repro.launch.roofline.HW()`` (TPU v5e).
+        ``hw`` defaults to the published peaks of the first device's
+        ``device_kind`` (``repro.launch.roofline.PEAKS``); a device with
+        no entry there gets no modeled roofline (an empty table).
         """
         if hw is None:
-            from repro.launch.roofline import HW
-            hw = HW()
+            import jax
+            from repro.launch.roofline import PEAKS
+            hw = PEAKS.get(jax.devices()[0].device_kind)
+            if hw is None:
+                return {}
         out = {}
         for fam, f in self.families.items():
             t_c = f["flops"] / hw.peak_flops

@@ -21,29 +21,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = ["ShardingRules", "DEFAULT_RULES", "zero_shard_spec",
            "make_abstract_mesh", "shard_map_unchecked"]
 
-try:  # jax>=0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across jax versions:
-    jax >= 0.6 spells the kwarg ``check_vma``, older jax ``check_rep``."""
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _shard_map(f, check_vma=False, **kw)
-    except TypeError:
-        return _shard_map(f, check_rep=False, **kw)
+    """``jax.shard_map`` with replication (vma) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions: 0.4.x wants one iterable of
-    (name, size) pairs, >= 0.5 wants (axis_sizes, axis_names)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    """``jax.sharding.AbstractMesh`` of the given axis sizes and names."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 # logical axis -> mesh axis name(s) or None. 'dp' expands to the mesh's
 # data-parallel axes (('pod','data') or ('data',)).

@@ -77,7 +77,6 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.ann.engine import SearchConfig
-from repro.core import packing as _packing
 from repro.kernels import ops as _ops
 from repro.obs import (MetricsRegistry, TailSampler,
                        default_flight_recorder, span)
@@ -593,8 +592,8 @@ class AnnService:
             miss = list(range(n))
             keys = None
             if cfg.cache_size:
-                words = np.asarray(_packing.pack_codes(
-                    q_codes, self.engine.store.bits))
+                words = np.asarray(_ops.pack_codes(
+                    q_codes, self.engine.store.bits, impl=cfg.impl))
                 keys = [self._cache_key(words[i]) for i in range(n)]
                 miss = []
                 for i, key in enumerate(keys):

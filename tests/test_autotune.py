@@ -140,7 +140,7 @@ def test_tune_injected_measure_picks_argmin(iso_cache):
 
 def test_tune_skips_raising_candidates(iso_cache):
     """Candidates that fail (VMEM overflow etc.) are skipped; the best
-    surviving one wins. All failing -> {} and nothing cached."""
+    surviving one wins. All failing -> raises, nothing cached."""
     def fragile_measure(run, config):
         if config["block_q"] > 64:
             raise RuntimeError("tile too large")
@@ -154,11 +154,29 @@ def test_tune_skips_raising_candidates(iso_cache):
     def all_fail(run, config):
         raise RuntimeError("no")
 
-    assert autotune.tune("packed_topk", lambda c: None, "uint32",
-                         dict(q=9, n=5000, w=4, top_k=8),
-                         measure=all_fail) == {}
+    with pytest.raises(RuntimeError, match="every candidate failed"):
+        autotune.tune("packed_topk", lambda c: None, "uint32",
+                      dict(q=9, n=5000, w=4, top_k=8), measure=all_fail)
     assert autotune.lookup("packed_topk", "uint32", q=9, n=5000, w=4,
                            top_k=8) == {}
+
+
+def test_tune_all_failing_names_each_candidate(iso_cache):
+    """When no candidate compiles, the error lists every candidate with
+    its own failure, so a refusal by the chip's compiler is readable
+    from the one exception."""
+    def refuse(run, config):
+        raise ValueError(f"refused block_n={config['block_n']}")
+
+    with pytest.raises(RuntimeError) as err:
+        autotune.tune("packed_topk", lambda c: None, "uint32",
+                      dict(q=8, n=64, w=4, top_k=8), measure=refuse)
+    msg = str(err.value)
+    for config in autotune.candidate_configs("packed_topk"):
+        assert str(config) in msg
+    assert msg.count("ValueError: refused block_n=") == len(
+        autotune.candidate_configs("packed_topk"))
+    assert len(iso_cache) == 0
 
 
 def test_tune_search_ops_with_injected_measure(iso_cache):

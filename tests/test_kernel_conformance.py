@@ -240,6 +240,64 @@ def test_packed_topk_masked_conformance(bits, q, n, k, density):
     assert not (set(np.asarray(got[1]).ravel()) - {-1}) & dead
 
 
+# the running top-k merge (shared by every streaming family): ties go to
+# the lowest corpus id, running sentinels outlast equal-keyed rows
+MERGE_CASES = [
+    pytest.param("all-equal", id="all-counts-equal"),
+    pytest.param("few-live", id="top_k-gt-live-rows"),
+    pytest.param("dead-tile", id="all-tombstoned-tile"),
+]
+
+
+@pytest.mark.parametrize("family", ["counts", "lut", "fused"])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_running_merge_ties_and_sentinels(family, case):
+    """Merge tie rule and sentinel padding, bit-exact vs the oracles:
+    every row equal to the query (ids must come out 0, 1, 2, ...), fewer
+    live rows than top_k ((-1, -1) / (-inf, -1) fill the tail), and a
+    whole 32-row tile tombstoned (its rows never surface)."""
+    q, n, k, bits, top_k, m = 3, 96, 33, 2, 12, 40
+    key = jax.random.PRNGKey(len(case) * 7 + len(family))
+    wq = PK.pack_codes(_codes(key, (q, k), bits), bits)
+    if case == "all-equal":
+        wdb = jnp.broadcast_to(wq[:1], (n, wq.shape[1]))
+        live = jnp.ones((n,), bool)
+    else:
+        wdb = PK.pack_codes(_codes(jax.random.fold_in(key, 1), (n, k),
+                                   bits), bits)
+        rows = jnp.arange(n)
+        live = (rows % 19 == 3) if case == "few-live" else \
+            (rows // 32 != 1)
+    vwords = PK.pack_bitmask(live)
+    tab, _ = _tables(jax.random.fold_in(key, 2), q, k, bits, "f32")
+    blocks = dict(block_q=8, block_n=32, interpret=True)
+    if family == "counts":
+        got = packed_topk_masked_pallas(wq, wdb, vwords, bits, k, top_k,
+                                        **blocks)
+        want = ref.packed_topk_masked_ref(wq, wdb, vwords, bits, k, top_k)
+    elif family == "lut":
+        got = packed_lut_topk_masked_pallas(tab, wdb, vwords, bits, top_k,
+                                            **blocks)
+        want = ref.packed_lut_topk_masked_ref(tab, wdb, vwords, bits, top_k)
+    else:
+        got = fused_scored_topk_masked_pallas(wq, tab, wdb, vwords, bits,
+                                              k, m, top_k, **blocks)
+        want = ref.fused_scored_topk_masked_ref(wq, tab, wdb, vwords, bits,
+                                                k, m, top_k)
+    _eq_pairs(got, want)
+    ids = np.asarray(got[1])
+    if case == "all-equal":
+        assert (ids == np.arange(top_k)[None, :]).all()
+    elif case == "few-live":
+        n_live = int(np.sum(np.asarray(live)))
+        assert n_live < top_k and (ids[:, n_live:] == -1).all()
+        empty = np.asarray(got[0])[:, n_live:]
+        assert (empty == -1).all() if family == "counts" else \
+            np.isneginf(empty).all()
+    else:
+        assert not ((ids >= 32) & (ids < 64)).any()
+
+
 # -- LUT scoring --------------------------------------------------------------
 
 @pytest.mark.parametrize("bits", BITS)
@@ -468,3 +526,15 @@ def test_ops_dispatch_cpu_uses_ref():
     r = jnp.ones((8, 4), jnp.float32)
     out = ops.coded_project(x, r, CodeSpec("sign", 1.0))
     np.testing.assert_array_equal(np.asarray(out), 1)
+
+
+@pytest.mark.parametrize("backend,auto,interpret", [
+    ("tpu", "pallas", False), ("cpu", "ref", True)])
+def test_ops_dispatch_policy(monkeypatch, backend, auto, interpret):
+    """On a TPU, impl='auto' is the compiled kernel — never the oracle,
+    never interpret mode; 'ref' and 'pallas' stay explicit choices."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    assert ops._resolve("auto") == auto
+    assert ops._resolve("ref") == "ref"
+    assert ops._resolve("pallas") == "pallas"
+    assert ops._interpret() is interpret

@@ -17,7 +17,7 @@ from repro.core import packing as PK
 from repro.core.sketch import CodedRandomProjection, SketchConfig
 from repro.index import MutableAnnEngine
 from repro.kernels import ops as _ops
-from repro.launch.roofline import HW
+from repro.launch.roofline import V5E
 from repro.obs import (KernelStats, MetricsRegistry, Tracer,
                        default_registry, no_tracing, set_default_registry,
                        set_kernel_stats, snapshot, span, to_prometheus,
@@ -285,7 +285,7 @@ def test_kernel_stats_disabled_by_registry_switch():
 def test_roofline_table_terms_consistent():
     ks = KernelStats()
     ks.record("coded_project", m=64, d=D, k=K)
-    hw = HW()
+    hw = V5E
     row = ks.roofline_table(hw)["coded_project"]
     np.testing.assert_allclose(row["t_compute_s"],
                                row["flops"] / hw.peak_flops)

@@ -1,6 +1,6 @@
 """Roofline helpers: HLO collective parsing + term math."""
-from repro.launch.roofline import (HW, collective_bytes, roofline_terms,
-                                   _shape_bytes)
+from repro.launch.roofline import (PEAKS, V5E, collective_bytes,
+                                   roofline_terms, _shape_bytes)
 
 HLO = """
 ENTRY %main {
@@ -36,7 +36,7 @@ def test_collective_bytes_parses_all_kinds():
 
 
 def test_roofline_terms_dominance():
-    hw = HW()
+    hw = V5E
     t = roofline_terms(197e12, 0.0, 0.0, hw)   # 1s compute, nothing else
     assert t["dominant"] == "compute" and abs(t["t_compute_s"] - 1.0) < 1e-9
     assert t["roofline_fraction"] == 1.0
@@ -63,9 +63,30 @@ def test_kernelstats_roofline_agrees_with_roofline_terms():
     from repro.obs import KernelStats
     ks = KernelStats()
     ks.record("coded_project", m=256, d=64, k=64)
-    hw = HW()
+    hw = V5E
     row = ks.roofline_table(hw)["coded_project"]
     terms = roofline_terms(row["flops"], row["hbm_bytes"], 0.0, hw)
     assert row["t_compute_s"] == terms["t_compute_s"]
     assert row["t_memory_s"] == terms["t_memory_s"]
     assert row["bound"] == terms["dominant"]
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    """The v5e entry carries the published figures; a kind with no
+    entry has none, rather than another chip's."""
+    assert PEAKS["TPU v5 lite"] is V5E
+    assert (V5E.peak_flops, V5E.peak_int8_ops, V5E.hbm_bw) == (
+        197e12, 393e12, 819e9)
+    for kind in ("cpu", "TPU v4", "TPU v6 lite"):
+        assert PEAKS.get(kind) is None
+
+
+def test_kernelstats_roofline_empty_for_unlisted_device():
+    """Off the table (the CPU here), the live roofline models nothing."""
+    import jax
+    from repro.obs import KernelStats
+    assert jax.devices()[0].device_kind not in PEAKS
+    ks = KernelStats()
+    ks.record("coded_project", m=256, d=64, k=64)
+    assert ks.roofline_table() == {}
+    assert set(ks.roofline_table(V5E)) == {"coded_project"}
