@@ -42,8 +42,8 @@ class IngestPipeline:
     rebound on ``self.store`` per chunk — read it back after
     ``ingest``).  ``stats`` is a read-only view of the ``repro.obs``
     counters accumulating rows, chunks and packed bytes across calls;
-    per-chunk encode latency lands in the ``encode.chunk_s`` histogram
-    and each chunk opens an ``encode.chunk`` span when tracing.
+    each call opens an ``encode.ingest`` span and each chunk an
+    ``encode.chunk`` span, on the profiler's clock.
     """
 
     def __init__(self, encoder: StreamingEncoder, store, *,
@@ -60,7 +60,6 @@ class IngestPipeline:
         self._c_rows = self.registry.counter("encode.rows")
         self._c_chunks = self.registry.counter("encode.chunks")
         self._c_bytes = self.registry.counter("encode.packed_bytes")
-        self._h_chunk = self.registry.histogram("encode.chunk_s")
 
     @property
     def stats(self):
@@ -125,10 +124,8 @@ class IngestPipeline:
         with span("encode.ingest", rows=n) as sp:
             for lo in range(0, n, self.chunk_rows):
                 hi = min(lo + self.chunk_rows, n)
-                t0 = time.perf_counter()
                 with span("encode.chunk", rows=hi - lo) as csp:
                     words = csp.sync(self._encode_chunk(x, lo, hi))
-                self._h_chunk.observe(time.perf_counter() - t0)
                 chunk_ids = None if ids is None else ids[lo:hi]
                 if hasattr(self.store, "add_codes"):        # mutable log
                     out_ids.append(np.asarray(

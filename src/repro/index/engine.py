@@ -225,7 +225,8 @@ class MutableAnnEngine:
             return (jnp.full((q, cfg.top_k), -1, jnp.int32),
                     jnp.full((q, cfg.top_k), -1.0, jnp.float32))
         t0 = _time.perf_counter()
-        out = run_chunked(q_codes, cfg, self._search_chunk)
+        with span("engine.search", queries=int(q)):
+            out = run_chunked(q_codes, cfg, self._search_chunk)
         default_flight_recorder().record(
             "index.search", t0, _time.perf_counter(), batch=int(q),
             generation=self.generation, outcome=cfg.mode,

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro.ann.bands import BandSpec, band_hashes
 from repro.core import packing as _packing
 from repro.kernels import ops as _ops
-from repro.obs import MetricsRegistry
+from repro.obs import MetricsRegistry, span
 
 __all__ = ["Segment", "SegmentLogStore"]
 
@@ -259,14 +259,15 @@ class SegmentLogStore:
         shape = np.shape(codes)          # no copy/transfer, any array type
         if len(shape) != 2 or shape[1] != self.k:
             raise ValueError(f"codes {shape} != [m, {self.k}]")
-        ids = self._prepare_ids(ids, shape[0])
-        if shape[0] == 0:
-            return ids
-        codes = jnp.asarray(codes)
-        words = _ops.pack_codes(codes, self.bits, impl=self.impl)
-        hashes = (band_hashes(codes, self.band_spec)
-                  if self.band_spec else None)
-        return self._append(words, hashes, ids)
+        with span("store.append", rows=shape[0]):
+            ids = self._prepare_ids(ids, shape[0])
+            if shape[0] == 0:
+                return ids
+            codes = jnp.asarray(codes)
+            words = _ops.pack_codes(codes, self.bits, impl=self.impl)
+            hashes = (band_hashes(codes, self.band_spec)
+                      if self.band_spec else None)
+            return self._append(words, hashes, ids)
 
     def add_words(self, words, ids=None) -> np.ndarray:
         """Append already-packed uint32 rows [m, W] (the fused-ingest
@@ -277,17 +278,18 @@ class SegmentLogStore:
         shape = np.shape(words)          # no copy/transfer, any array type
         if len(shape) != 2 or shape[1] != self.n_words:
             raise ValueError(f"words {shape} != [m, {self.n_words}]")
-        ids = self._prepare_ids(ids, shape[0])
-        if shape[0] == 0:
-            return ids
-        words = jnp.asarray(words, jnp.uint32)
-        if self.band_spec:
-            hashes = band_hashes(
-                _packing.unpack_codes(words, self.bits, self.k),
-                self.band_spec)
-        else:
-            hashes = None
-        return self._append(words, hashes, ids)
+        with span("store.append", rows=shape[0]):
+            ids = self._prepare_ids(ids, shape[0])
+            if shape[0] == 0:
+                return ids
+            words = jnp.asarray(words, jnp.uint32)
+            if self.band_spec:
+                hashes = band_hashes(
+                    _packing.unpack_codes(words, self.bits, self.k),
+                    self.band_spec)
+            else:
+                hashes = None
+            return self._append(words, hashes, ids)
 
     def _prepare_ids(self, ids, m: int) -> np.ndarray:
         """Validate/auto-assign a batch's external ids — runs before any
@@ -342,13 +344,14 @@ class SegmentLogStore:
             if tp > t:
                 hc = jnp.pad(hc, ((0, tp - t), (0, 0)))
             tail.hashes = _write_rows(tail.hashes, hc, start)
-        rows = np.arange(start, start + t)
-        tail.ids[start:start + t] = ids[pos:pos + t]
-        np.bitwise_or.at(tail.valid, rows // 32,
-                         np.uint32(1) << (rows % 32).astype(np.uint32))
-        self._by_id.update(
-            (int(item), (tail, start + j))
-            for j, item in enumerate(ids[pos:pos + t]))
+        with span("store.id_map", rows=t):
+            rows = np.arange(start, start + t)
+            tail.ids[start:start + t] = ids[pos:pos + t]
+            np.bitwise_or.at(tail.valid, rows // 32,
+                             np.uint32(1) << (rows % 32).astype(np.uint32))
+            self._by_id.update(
+                (int(item), (tail, start + j))
+                for j, item in enumerate(ids[pos:pos + t]))
         tail.live += t
         tail.length += t
         tail._valid_dev = None
@@ -357,9 +360,10 @@ class SegmentLogStore:
     def _seal_tail(self):
         """The full tail becomes a sealed segment as-is (no copy: the id
         map keys on the Segment object, which just moves lists)."""
-        self.sealed.append(self.tail)
-        self.tail = self._new_tail()
-        self._c_seals.inc()
+        with span("store.seal", segment=len(self.sealed)):
+            self.sealed.append(self.tail)
+            self.tail = self._new_tail()
+            self._c_seals.inc()
 
     # -- deletes / upserts ---------------------------------------------------
     def delete(self, ids, strict: bool = True) -> int:

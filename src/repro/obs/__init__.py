@@ -9,10 +9,14 @@ registry    — ``MetricsRegistry``: counters, gauges, fixed-log-bucket
               histograms (p50/p95/p99 without storing samples);
               process-global default + injectable instances; a disabled
               registry hands out no-op metrics
-trace       — ``Tracer``/``span``: nestable spans with device-sync-
-              correct timing (``sp.sync`` = ``block_until_ready`` at
-              the boundary; unsynced spans are *marked* async — the
-              sync-boundary invariant) and Chrome-trace/Perfetto export
+trace       — ``Tracer``/``span``: nestable spans, always written to
+              the profiler's clock as ``jax.profiler`` annotations,
+              recorded in Python with device-sync-correct timing while
+              a ``Tracer`` is installed (``sp.sync`` =
+              ``block_until_ready`` at the boundary; unsynced spans
+              are *marked* async — the sync-boundary invariant) and
+              exported as Chrome-trace/Perfetto JSON;
+              ``install_gc_spans`` marks garbage collections
 kernelstats — per-kernel-family dispatch counts + modeled FLOPs/HBM
               bytes recorded at the ``kernels/ops.py`` chokepoint; live
               roofline table against ``launch.roofline.HW``
@@ -67,11 +71,13 @@ errored / quality-flagged requests, with exemplar links
 (``Histogram.exemplar``) exported on Prometheus buckets.
 
 Instrumented layers: ``serve.ann_service`` (endpoint latencies, ticket
-age, cache + padding economics, per-request flight events + tail
-sampling), ``encode.pipeline`` (chunk spans, rows/bytes),
-``index.segment_log``/``index.compaction`` (churn counters,
-live-fraction gauge), ``ann.engine``/``index.engine`` (coarse vs.
-re-rank span split), ``learn.trainer`` (step time, rows/s). Overhead is
+age and queue wait, cache + padding economics, per-request flight
+events + tail sampling, a span per flush stage),
+``encode.pipeline`` (chunk spans, rows/bytes),
+``index.segment_log``/``index.compaction`` (append/id-map/seal spans,
+churn counters, live-fraction gauge), ``ann.engine``/``index.engine``
+(search span around the coarse vs. re-rank span split),
+``learn.trainer`` (step time, rows/s). Overhead is
 benchmarked by ``benchmarks/obs_bench.py`` (``BENCH_obs.json``); any
 bench target exports a flame view via ``benchmarks/run.py --profile``;
 cross-run headline numbers accumulate in ``BENCH_history.jsonl``
@@ -83,8 +89,8 @@ from repro.obs.registry import (Counter, Gauge, Histogram,  # noqa: F401
                                 default_registry, set_default_registry)
 from repro.obs.trace import (RequestTrace, Span,  # noqa: F401
                              TailSampler, Tracer, active_tracer,
-                             deep_tracing_active, no_tracing, span,
-                             tracing_active)
+                             deep_tracing_active, install_gc_spans,
+                             no_tracing, span, tracing_active)
 from repro.obs.events import (EVENT_FIELDS,  # noqa: F401
                               FlightRecorder, default_flight_recorder,
                               set_flight_recorder)

@@ -29,12 +29,17 @@ two workloads over one set of codes.
 Observability: every endpoint reports through a ``repro.obs``
 ``MetricsRegistry`` (per-service instance by default; inject a shared
 one via the ``registry`` field) — latency histograms (``serve.flush_s``,
-``serve.search_batch_s``, ``serve.classify_s``), ticket age from
-``submit`` to result (``serve.ticket_age_s``), cache hit/miss/eviction/
-invalidation and warmup-compile counters, and a padding-waste gauge.
-The old ad-hoc ``stats`` dict survives as a read-only compat property
-derived from the counters. ``flush``/``classify`` also open tracing
-spans when a ``repro.obs.Tracer`` is installed.
+``serve.classify_s``), ticket age from ``submit`` to result
+(``serve.ticket_age_s``), the summed queue wait from ``submit`` to the
+start of the flush slice that answers a ticket
+(``serve.queue_wait_s``), cache hit/miss/eviction/invalidation and
+warmup-compile counters, and a padding-waste gauge. The old ad-hoc
+``stats`` dict survives as a read-only compat property derived from the
+counters. Spans on the profiler's clock mark each stage of the request
+path — ``serve.submit``; inside ``serve.flush`` per slice
+``serve.batch``, ``serve.encode``, ``serve.cache_key``, the engine's
+``engine.search`` and ``serve.fetch`` — and building a service brackets
+garbage collections in ``runtime.gc`` (``obs.install_gc_spans``).
 
 Flight recorder: every endpoint additionally appends a structured
 event (op, queue/start/sync timestamps, batch shape, cache hits, store
@@ -79,7 +84,7 @@ import jax.numpy as jnp
 from repro.ann.engine import SearchConfig
 from repro.kernels import ops as _ops
 from repro.obs import (MetricsRegistry, TailSampler,
-                       default_flight_recorder, span)
+                       default_flight_recorder, install_gc_spans, span)
 
 __all__ = ["AnnServiceConfig", "AnnService"]
 
@@ -142,13 +147,14 @@ class AnnService:
         self._c_classified = reg.counter("serve.classified_rows")
         self._c_flush_err = reg.counter("serve.flush_errors")
         self._c_classify_err = reg.counter("serve.classify_errors")
+        self._c_wait = reg.counter("serve.queue_wait_s")
         self._h_flush = reg.histogram("serve.flush_s")
-        self._h_batch = reg.histogram("serve.search_batch_s")
         self._h_age = reg.histogram("serve.ticket_age_s")
         self._h_classify = reg.histogram("serve.classify_s")
         self._g_pending = reg.gauge("serve.pending")
         self._g_waste = reg.gauge("serve.padding_waste")
         self._probing = False
+        install_gc_spans()
         if self.flight is None:
             self.flight = default_flight_recorder()
         if self.sampler is None:
@@ -230,19 +236,22 @@ class AnnService:
             "cache_evictions": self._c_evict.value,
             "cache_invalidations": self._c_inval.value,
             "warmup_compiles": self._c_warm.value,
+            "queue_wait_s": self._c_wait.value,
         })
 
     # -- request path --------------------------------------------------------
     def submit(self, x) -> int:
         """Enqueue one query vector [D]; returns a ticket for ``result``."""
-        x = jnp.asarray(x)
-        if x.ndim != 1:
-            raise ValueError(f"submit takes a single vector, got {x.shape}")
-        t = self._next_ticket
-        self._next_ticket += 1
-        self._queue.append((t, x))
-        self._submit_ts[t] = time.perf_counter()
-        self._g_pending.set(len(self._queue))
+        with span("serve.submit"):
+            x = jnp.asarray(x)
+            if x.ndim != 1:
+                raise ValueError(
+                    f"submit takes a single vector, got {x.shape}")
+            t = self._next_ticket
+            self._next_ticket += 1
+            self._queue.append((t, x))
+            self._submit_ts[t] = time.perf_counter()
+            self._g_pending.set(len(self._queue))
         return t
 
     def result(self, ticket: int):
@@ -432,7 +441,8 @@ class AnnService:
         t_flush = time.perf_counter()
         with self.sampler.request("search",
                                   pending=len(self._queue)) as rq:
-            with span("serve.flush", pending=len(self._queue)) as sp:
+            with span("serve.flush", meta=True, pending=len(self._queue),
+                      trace_id=rq.trace_id) as sp:
                 try:
                     out = self._flush(sp, rq)
                 except Exception as e:
@@ -470,15 +480,13 @@ class AnnService:
         probe exercises exactly what user traffic exercises, stale
         cache included."""
         reg = self.registry
-        saved = (self._h_flush, self._h_batch, self._h_age,
-                 self._h_classify, self._c_queries, self._c_hits,
-                 self._c_misses, self._c_batches, self._c_padded,
-                 self._c_classified, self._c_flush_err,
-                 self._c_classify_err, self._g_waste, self.sampler,
-                 self.quality)
+        saved = (self._h_flush, self._h_age, self._h_classify,
+                 self._c_queries, self._c_hits, self._c_misses,
+                 self._c_batches, self._c_padded, self._c_classified,
+                 self._c_flush_err, self._c_classify_err, self._c_wait,
+                 self._g_waste, self.sampler, self.quality)
         eng_quality = getattr(self.engine, "quality", None)
         self._h_flush = reg.histogram("serve.probe.flush_s")
-        self._h_batch = reg.histogram("serve.probe.search_batch_s")
         self._h_age = reg.histogram("serve.probe.ticket_age_s")
         self._h_classify = reg.histogram("serve.probe.classify_s")
         self._c_queries = reg.counter("serve.probe.queries")
@@ -489,6 +497,7 @@ class AnnService:
         self._c_classified = reg.counter("serve.probe.classified_rows")
         self._c_flush_err = reg.counter("serve.probe.flush_errors")
         self._c_classify_err = reg.counter("serve.probe.classify_errors")
+        self._c_wait = reg.counter("serve.probe.queue_wait_s")
         self._g_waste = reg.gauge("serve.probe.padding_waste")
         self.sampler = _PROBE_SAMPLER
         self.quality = None
@@ -498,12 +507,11 @@ class AnnService:
         try:
             yield
         finally:
-            (self._h_flush, self._h_batch, self._h_age,
-             self._h_classify, self._c_queries, self._c_hits,
-             self._c_misses, self._c_batches, self._c_padded,
-             self._c_classified, self._c_flush_err,
-             self._c_classify_err, self._g_waste, self.sampler,
-             self.quality) = saved
+            (self._h_flush, self._h_age, self._h_classify,
+             self._c_queries, self._c_hits, self._c_misses,
+             self._c_batches, self._c_padded, self._c_classified,
+             self._c_flush_err, self._c_classify_err, self._c_wait,
+             self._g_waste, self.sampler, self.quality) = saved
             if eng_quality is not None:
                 self.engine.quality = eng_quality
             self._probing = False
@@ -566,16 +574,19 @@ class AnnService:
         max_age = 0.0
         trace_id = rq.trace_id if rq is not None else 0
         while self._queue:
+            t_slice = time.perf_counter()
             batch = self._queue[:max_b]
             self._queue = self._queue[max_b:]
             n = len(batch)
             # pad to the bucket BEFORE any device work, so every jit'd
             # stage (encode included) only ever sees bucket shapes
             b = self._bucket_for(n)
-            x = jnp.stack([v for _, v in batch])
-            if b > n:
-                x = jnp.pad(x, ((0, b - n), (0, 0)))
-            q_codes = self.engine.encode_queries(x, impl=cfg.impl)
+            with span("serve.batch"):
+                x = jnp.stack([v for _, v in batch])
+                if b > n:
+                    x = jnp.pad(x, ((0, b - n), (0, 0)))
+            with span("serve.encode"):
+                q_codes = self.engine.encode_queries(x, impl=cfg.impl)
             qm = self.quality
             if qm is not None and qm.sample():
                 # budgeted shadow check of one real (unpadded) query:
@@ -592,17 +603,18 @@ class AnnService:
             miss = list(range(n))
             keys = None
             if cfg.cache_size:
-                words = np.asarray(_ops.pack_codes(
-                    q_codes, self.engine.store.bits, impl=cfg.impl))
-                keys = [self._cache_key(words[i]) for i in range(n)]
-                miss = []
-                for i, key in enumerate(keys):
-                    hit = self._cache.get(key)
-                    if hit is not None:
-                        self._cache.move_to_end(key)
-                        res[i] = hit
-                    else:
-                        miss.append(i)
+                with span("serve.cache_key"):
+                    words = np.asarray(_ops.pack_codes(
+                        q_codes, self.engine.store.bits, impl=cfg.impl))
+                    keys = [self._cache_key(words[i]) for i in range(n)]
+                    miss = []
+                    for i, key in enumerate(keys):
+                        hit = self._cache.get(key)
+                        if hit is not None:
+                            self._cache.move_to_end(key)
+                            res[i] = hit
+                        else:
+                            miss.append(i)
             if miss:
                 if len(miss) == n:
                     sub, b2 = q_codes, b          # already bucket-shaped
@@ -622,41 +634,44 @@ class AnnService:
                                       rerank_m=cfg.rerank_m,
                                       fused=cfg.fused,
                                       table_dtype=cfg.table_dtype))
-                # host transfer is the device sync for this batch's
-                # timing (np.asarray blocks on the result buffers)
-                ids, rho = np.asarray(sp.sync(ids)), np.asarray(rho)
-                t_done = time.perf_counter()
-                self._h_batch.observe(t_done - t_batch)
-                self.flight.record(
-                    "serve.search", t_batch, t_done,
-                    t_queue=min(self._submit_ts.get(t, t_batch)
-                                for t, _ in batch),
-                    batch=b2, cache_hits=n - len(miss),
-                    generation=self._cache_gen or 0,
-                    trace_id=trace_id, synced=True)
-                for j, i in enumerate(miss):
-                    res[i] = (ids[j], rho[j])
-                    if cfg.cache_size:
-                        self._cache[keys[i]] = res[i]
-                        while len(self._cache) > cfg.cache_size:
-                            self._cache.popitem(last=False)
-                            self._c_evict.inc()
-                self._c_batches.inc()
-                self._c_padded.inc(b2 - len(miss))
-                self._g_waste.set((b2 - len(miss)) / b2)
-            now = time.perf_counter()
-            for (t, _), r in zip(batch, res):
-                self._results[t] = r
-                out[t] = r
-                t0 = self._submit_ts.pop(t, None)
-                if t0 is not None:
-                    age = now - t0
-                    self._h_age.observe(age)
-                    if age > max_age:
-                        max_age = age
-            self._c_queries.inc(n)
-            self._c_hits.inc(n - len(miss))
-            self._c_misses.inc(len(miss))
+            with span("serve.fetch"):
+                if miss:
+                    # host transfer is the device sync for this batch
+                    # (np.asarray blocks on the result buffers)
+                    ids, rho = np.asarray(sp.sync(ids)), np.asarray(rho)
+                    self.flight.record(
+                        "serve.search", t_batch, time.perf_counter(),
+                        t_queue=min(self._submit_ts.get(t, t_batch)
+                                    for t, _ in batch),
+                        batch=b2, cache_hits=n - len(miss),
+                        generation=self._cache_gen or 0,
+                        trace_id=trace_id, synced=True)
+                    for j, i in enumerate(miss):
+                        res[i] = (ids[j], rho[j])
+                        if cfg.cache_size:
+                            self._cache[keys[i]] = res[i]
+                            while len(self._cache) > cfg.cache_size:
+                                self._cache.popitem(last=False)
+                                self._c_evict.inc()
+                    self._c_batches.inc()
+                    self._c_padded.inc(b2 - len(miss))
+                    self._g_waste.set((b2 - len(miss)) / b2)
+                now = time.perf_counter()
+                wait = 0.0
+                for (t, _), r in zip(batch, res):
+                    self._results[t] = r
+                    out[t] = r
+                    t0 = self._submit_ts.pop(t, None)
+                    if t0 is not None:
+                        wait += t_slice - t0
+                        age = now - t0
+                        self._h_age.observe(age)
+                        if age > max_age:
+                            max_age = age
+                self._c_wait.inc(wait)
+                self._c_queries.inc(n)
+                self._c_hits.inc(n - len(miss))
+                self._c_misses.inc(len(miss))
         if rq is not None:
             # deadline-relative lateness keys the slow-tail reservoir:
             # a flush is "slow" when its oldest ticket beat the SLO by

@@ -25,7 +25,7 @@ from repro.obs import (KernelStats, MetricsRegistry, Tracer,
 from repro.obs.kernelstats import model
 from repro.obs.registry import (NULL_COUNTER, NULL_GAUGE, NULL_HISTOGRAM,
                                 HistogramSpec)
-from repro.obs.trace import _NULL_SPAN
+from repro.obs.trace import _AnnotationSpan
 from repro.serve.ann_service import AnnService, AnnServiceConfig
 
 D, K = 16, 16
@@ -187,9 +187,12 @@ def test_sync_boundary_invariant():
 
 
 def test_span_without_tracer_is_shared_noop():
+    """With no tracer a span is the profiler annotation alone: nothing
+    is recorded on the Python side and ``sync`` never blocks."""
     assert not tracing_active()
-    assert span("x") is _NULL_SPAN         # no allocation per call site
+    assert type(span("x")) is _AnnotationSpan
     with span("x") as sp:
+        sp.set(ignored=1)
         out = sp.sync(jnp.ones(4))         # passthrough
     np.testing.assert_array_equal(np.asarray(out), np.ones(4))
 
@@ -333,24 +336,26 @@ def test_service_metrics_under_mutation_and_search():
                                            cache_size=8))
     svc.add(jnp.asarray(rng.normal(size=(20, D)), jnp.float32))
     q = jnp.asarray(rng.normal(size=(D,)), jnp.float32)
-    svc.submit(q)
-    svc.flush()
-    svc.submit(q)
-    svc.flush()                            # cache hit
-    assert svc.stats["queries"] == 2
-    assert svc.stats["cache_hits"] == 1
-    assert svc.stats["cache_misses"] == 1
-    assert svc.stats["cache_invalidations"] == 0
-    # a mutation invalidates the (non-empty) cache on the next flush
-    svc.add(jnp.asarray(rng.normal(size=(4, D)), jnp.float32))
-    svc.submit(q)
-    svc.flush()
+    with Tracer() as tr:
+        svc.submit(q)
+        svc.flush()
+        svc.submit(q)
+        svc.flush()                            # cache hit
+        assert svc.stats["queries"] == 2
+        assert svc.stats["cache_hits"] == 1
+        assert svc.stats["cache_misses"] == 1
+        assert svc.stats["cache_invalidations"] == 0
+        # a mutation invalidates the (non-empty) cache on the next flush
+        svc.add(jnp.asarray(rng.normal(size=(4, D)), jnp.float32))
+        svc.submit(q)
+        svc.flush()
     assert svc.stats["cache_invalidations"] == 1
     assert svc.stats["cache_misses"] == 2
     reg = svc.registry
     assert reg.histograms["serve.flush_s"].count == 3
     assert reg.histograms["serve.ticket_age_s"].count == 3
-    assert reg.histograms["serve.search_batch_s"].count == 2
+    # two engine searches: the cache hit never reaches the engine
+    assert len(tr.durations("engine.search")) == 2
     assert reg.gauges["serve.pending"].value == 0.0
     # stats is a read-only compat view
     with pytest.raises(TypeError):
@@ -407,11 +412,14 @@ def test_pipeline_stats_compat_and_registry():
     store = SegmentLogStore(K, 2, tail_rows=64)
     pipe = IngestPipeline(crp.stream_encoder(), store, chunk_rows=32)
     rng = np.random.default_rng(11)
-    pipe.ingest(jnp.asarray(rng.normal(size=(70, D)), jnp.float32))
+    with Tracer() as tr:
+        pipe.ingest(jnp.asarray(rng.normal(size=(70, D)), jnp.float32))
     assert pipe.stats["rows"] == 70 and pipe.stats["chunks"] == 3
     assert pipe.stats["packed_bytes"] == \
         pipe.registry.counters["encode.packed_bytes"].value
-    assert pipe.registry.histograms["encode.chunk_s"].count == 3
+    chunks = [e for e in tr.events if e["name"] == "encode.chunk"]
+    assert [e["args"]["rows"] for e in chunks] == [32, 32, 6]
+    assert all(e["args"]["sync"] == "device" for e in chunks)
     with pytest.raises(TypeError):
         pipe.stats["rows"] = 0             # read-only compat view
 
