@@ -81,6 +81,10 @@ class MutableAnnEngine:
         self.band_spec = store.band_spec
         self._coder = QueryCoder(sketcher)
         self.quality = None       # obs.quality.QualityMonitors, if attached
+        # one int32 [2] device array per fused scored kernel call of the
+        # last ``search_codes``: the 8-row chunks it LUT-scored and all of
+        # them (the oracle path appends none)
+        self.last_lut_chunks = []
 
     # -- mutation ------------------------------------------------------------
     @property
@@ -221,6 +225,7 @@ class MutableAnnEngine:
                              "scored path (scored=True, fused=True, "
                              "mode='exact')")
         q = q_codes.shape[0]
+        self.last_lut_chunks = []
         if q == 0 or self.store.n_live == 0:
             return (jnp.full((q, cfg.top_k), -1, jnp.int32),
                     jnp.full((q, cfg.top_k), -1.0, jnp.float32))
@@ -267,7 +272,7 @@ class MutableAnnEngine:
                     vals, rows = _ops.fused_scored_topk_masked(
                         q_words, q_tables, seg.words, seg.valid_dev(),
                         bits, k, m, cfg.top_k, scales=scales,
-                        impl=cfg.impl)
+                        impl=cfg.impl, lut_chunks=self.last_lut_chunks)
                     sp.sync(vals)
                 ext = jnp.take(seg.ids_dev(),
                                jnp.clip(rows, 0, seg.cap - 1), axis=0)

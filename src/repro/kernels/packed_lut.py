@@ -7,8 +7,9 @@ field selects one of 2^b per-query float entries and the selections
 accumulate in float32 — a product-quantization-style asymmetric
 distance computation fused with streaming top-k.
 
-Three kernels, all sharing the field loop (``lut_scores``) and the
-running-top-k merge of ``packed_collision``:
+Three kernels, all sharing the field loop (``lut_scores``, which runs
+``lut_chunk`` over a tile's row chunks) and the running-top-k merge of
+``packed_collision``:
 
 ``packed_lut_topk_pallas``
     Full-corpus scored search: streams corpus words per query tile,
@@ -97,41 +98,49 @@ def corpus_words(db_ref, lanes: int):
     return words_at
 
 
-def lut_scores(tab_ref, words_at, score_ref, bits: int, scales_ref=None):
-    """LUT-score a transposed tile: score_ref f32 [bn, L] <- the sum over
-    every (word, field) slot of the table row the slot's code selects.
+def lut_chunk(tab_ref, words, bits: int, scales_ref=None):
+    """LUT scores f32 [rows, L] of one row chunk: the sum over every
+    (word, field) slot of the table row the slot's code selects.
 
     tab_ref [F*P, L] (f32, or int32-upcast int8 entries with
-    ``scales_ref`` f32 [W, L]); ``words_at(r0, rows)`` -> W uint32
+    ``scales_ref`` f32 [W, L]); ``words``: the chunk's W uint32
     [rows, L] arrays. Float tables accumulate in (word, field) order —
     the order of ``ref.lut_scores_ref`` / ``lut_scores_rowwise_ref``,
     so sums are bit-identical. int8 tables sum each word's 32/b entries
     exactly in int32 and join the float32 total as ``score += scale *
     float(isum)`` in word order (``ref.lut_scores_rowwise_int8_ref``).
     """
-    bn, lanes = score_ref.shape
+    rows, lanes = words[0].shape
     p = 1 << bits
     cpw = 32 // bits
-    rows = min(_LUT_ROWS, bn)
     mask = jnp.uint32(p - 1)
+    score = jnp.zeros((rows, lanes), jnp.float32)
+    for w, word in enumerate(words):
+        # float tables add straight into the score; int8 entries
+        # first sum exactly per word
+        acc = score if scales_ref is None else jnp.zeros(
+            (rows, lanes), jnp.int32)
+        for f in range(cpw):
+            code = (word >> jnp.uint32(f * bits)) & mask
+            base = (w * cpw + f) * p
+            acc = acc + _lut_select(
+                code, [tab_ref[base + i:base + i + 1, :] for i in range(p)])
+        score = acc if scales_ref is None else (
+            score + scales_ref[w:w + 1, :] * acc.astype(jnp.float32))
+    return score
+
+
+def lut_scores(tab_ref, words_at, score_ref, bits: int, scales_ref=None):
+    """LUT-score a transposed tile chunk by chunk (``lut_chunk``):
+    score_ref f32 [bn, L]; ``words_at(r0, rows)`` -> W uint32 [rows, L]
+    arrays."""
+    bn = score_ref.shape[0]
+    rows = min(_LUT_ROWS, bn)
 
     def chunk(c, carry):
         r0 = pl.multiple_of(c * rows, rows)
-        score = jnp.zeros((rows, lanes), jnp.float32)
-        for w, word in enumerate(words_at(r0, rows)):
-            # float tables add straight into the score; int8 entries
-            # first sum exactly per word
-            acc = score if scales_ref is None else jnp.zeros(
-                (rows, lanes), jnp.int32)
-            for f in range(cpw):
-                code = (word >> jnp.uint32(f * bits)) & mask
-                base = (w * cpw + f) * p
-                acc = acc + _lut_select(
-                    code, [tab_ref[base + i:base + i + 1, :]
-                           for i in range(p)])
-            score = acc if scales_ref is None else (
-                score + scales_ref[w:w + 1, :] * acc.astype(jnp.float32))
-        score_ref[pl.ds(r0, rows), :] = score
+        score_ref[pl.ds(r0, rows), :] = lut_chunk(
+            tab_ref, words_at(r0, rows), bits, scales_ref)
         return carry
 
     jax.lax.fori_loop(0, bn // rows, chunk, 0)

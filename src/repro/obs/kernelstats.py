@@ -81,8 +81,9 @@ def _m_packed_lut_rerank(q, c, w, t, k, top_k, **_):
 
 def _m_fused_scored_topk(q, n, w, t, k, top_k, **_):
     # two corpus sweeps: counts twice (~3 word ops each), the k+1-bin
-    # exceedance histogram in sweep A, LUT select+add per field in B
-    return (q * top_k, q * n * (6 * w + 3 * k + 1),
+    # exceedance histogram in sweep A; B's LUT select+add runs only in
+    # the 8-row chunks that hold a survivor, left out here
+    return (q * top_k, q * n * (6 * w + k + 1),
             4 * (q * w + q * t + 2 * n * w + 2 * q * top_k))
 
 

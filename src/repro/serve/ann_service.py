@@ -33,7 +33,10 @@ one via the ``registry`` field) — latency histograms (``serve.flush_s``,
 (``serve.ticket_age_s``), the summed queue wait from ``submit`` to the
 start of the flush slice that answers a ticket
 (``serve.queue_wait_s``), cache hit/miss/eviction/invalidation and
-warmup-compile counters, and a padding-waste gauge. The old ad-hoc
+warmup-compile counters, the 8-row chunks the fused scored kernel
+LUT-scored and all of its chunks (``serve.lut_chunks_scored``,
+``serve.lut_chunks``; fetched with the batch's ids), and a
+padding-waste gauge. The old ad-hoc
 ``stats`` dict survives as a read-only compat property derived from the
 counters. Spans on the profiler's clock mark each stage of the request
 path — ``serve.submit``; inside ``serve.flush`` per slice
@@ -148,6 +151,8 @@ class AnnService:
         self._c_flush_err = reg.counter("serve.flush_errors")
         self._c_classify_err = reg.counter("serve.classify_errors")
         self._c_wait = reg.counter("serve.queue_wait_s")
+        self._c_lut_scored = reg.counter("serve.lut_chunks_scored")
+        self._c_lut_chunks = reg.counter("serve.lut_chunks")
         self._h_flush = reg.histogram("serve.flush_s")
         self._h_age = reg.histogram("serve.ticket_age_s")
         self._h_classify = reg.histogram("serve.classify_s")
@@ -237,6 +242,8 @@ class AnnService:
             "cache_invalidations": self._c_inval.value,
             "warmup_compiles": self._c_warm.value,
             "queue_wait_s": self._c_wait.value,
+            "lut_chunks_scored": self._c_lut_scored.value,
+            "lut_chunks": self._c_lut_chunks.value,
         })
 
     # -- request path --------------------------------------------------------
@@ -484,6 +491,7 @@ class AnnService:
                  self._c_queries, self._c_hits, self._c_misses,
                  self._c_batches, self._c_padded, self._c_classified,
                  self._c_flush_err, self._c_classify_err, self._c_wait,
+                 self._c_lut_scored, self._c_lut_chunks,
                  self._g_waste, self.sampler, self.quality)
         eng_quality = getattr(self.engine, "quality", None)
         self._h_flush = reg.histogram("serve.probe.flush_s")
@@ -498,6 +506,8 @@ class AnnService:
         self._c_flush_err = reg.counter("serve.probe.flush_errors")
         self._c_classify_err = reg.counter("serve.probe.classify_errors")
         self._c_wait = reg.counter("serve.probe.queue_wait_s")
+        self._c_lut_scored = reg.counter("serve.probe.lut_chunks_scored")
+        self._c_lut_chunks = reg.counter("serve.probe.lut_chunks")
         self._g_waste = reg.gauge("serve.probe.padding_waste")
         self.sampler = _PROBE_SAMPLER
         self.quality = None
@@ -511,6 +521,7 @@ class AnnService:
              self._c_queries, self._c_hits, self._c_misses,
              self._c_batches, self._c_padded, self._c_classified,
              self._c_flush_err, self._c_classify_err, self._c_wait,
+             self._c_lut_scored, self._c_lut_chunks,
              self._g_waste, self.sampler, self.quality) = saved
             if eng_quality is not None:
                 self.engine.quality = eng_quality
@@ -634,11 +645,17 @@ class AnnService:
                                       rerank_m=cfg.rerank_m,
                                       fused=cfg.fused,
                                       table_dtype=cfg.table_dtype))
+                chunks = getattr(self.engine, "last_lut_chunks", ())
             with span("serve.fetch"):
                 if miss:
                     # host transfer is the device sync for this batch
                     # (np.asarray blocks on the result buffers)
                     ids, rho = np.asarray(sp.sync(ids)), np.asarray(rho)
+                    if chunks:
+                        scored, total = np.sum(
+                            [np.asarray(c) for c in chunks], axis=0).tolist()
+                        self._c_lut_scored.inc(scored)
+                        self._c_lut_chunks.inc(total)
                     self.flight.record(
                         "serve.search", t_batch, time.perf_counter(),
                         t_queue=min(self._submit_ts.get(t, t_batch)

@@ -487,6 +487,60 @@ def test_fused_scored_all_rows_tombstoned():
                                                    bits, k, 16, 5))
 
 
+def _survivor_chunks(wq, wdb, live, bits, k, m, block_q, block_n):
+    """(8-row chunks per query tile that hold a survivor of the stable
+    coarse top-m in some query lane, all 8-row chunks) — recomputed
+    from the oracle's counts."""
+    counts = ref.packed_collision_ref(wq, wdb, bits, k)
+    if live is not None:
+        counts = jnp.where(live[None, :], counts, -1)
+    vals, ids = ref.topk_stable_ref(counts, m)
+    q, n = counts.shape
+    hit = {(i // block_q, int(r) // 8)
+           for i, (v, r) in enumerate(zip(np.asarray(vals),
+                                          np.asarray(ids)))
+           for v, r in zip(v, r) if v >= 0}
+    n_pad = -(-n // block_n) * block_n
+    return len(hit), -(-q // block_q) * (n_pad // 8)
+
+
+@pytest.mark.parametrize("masked,table_dtype,n,density", [
+    pytest.param(False, "f32", 2048, None, id="plain-f32"),
+    pytest.param(False, "int8", 2000, None, id="plain-int8"),
+    pytest.param(True, "f32", 1000, 0.35, id="masked-f32"),
+    pytest.param(True, "int8", 2048, 0.35, id="masked-int8"),
+    pytest.param(True, "f32", 1024, 0.0, id="masked-all-dead"),
+])
+def test_fused_scored_gated_regime(masked, table_dtype, n, density):
+    """m so small against the corpus that most 8-row chunks hold no
+    survivor: the chunks left unscored change no score or id, and the
+    kernel's chunk count is the number of chunks that hold an oracle
+    survivor."""
+    q, k, bits, m, top_k, block_q, block_n = 3, 33, 2, 2, 2, 8, 256
+    key = jax.random.PRNGKey(n + 7 * masked)
+    wq, wdb, tab, scales = _fused_problem(key, q, n, k, bits, table_dtype)
+    kw = dict(scales=scales, block_q=block_q, block_n=block_n,
+              interpret=True, lut_chunks=True)
+    if masked:
+        flags, vwords = _mask(jax.random.fold_in(key, 9), n, density)
+        *got, chunks = fused_scored_topk_masked_pallas(
+            wq, tab, wdb, vwords, bits, k, m, top_k, **kw)
+        want = ref.fused_scored_topk_masked_ref(wq, tab, wdb, vwords, bits,
+                                                k, m, top_k, scales=scales)
+    else:
+        flags = None
+        *got, chunks = fused_scored_topk_pallas(wq, tab, wdb, bits, k, m,
+                                                top_k, **kw)
+        want = ref.fused_scored_topk_ref(wq, tab, wdb, bits, k, m, top_k,
+                                         scales=scales)
+    _eq_pairs(got, want, "gated kernel vs fused ref")
+    scored, total = _survivor_chunks(wq, wdb, flags, bits, k, m, block_q,
+                                     block_n)
+    assert np.asarray(chunks).tolist() == [scored, total]
+    # the regime under test: a survivor opens at most one chunk
+    assert scored <= q * m < total // 8
+
+
 @settings(max_examples=8, deadline=None)
 @given(st.sampled_from([8, 16, 32]),        # block_q
        st.sampled_from([32, 64, 128]),      # block_n

@@ -222,6 +222,42 @@ def test_service_scored_mode(scored_world):
                                   np.asarray(ids_direct[0]))
 
 
+def _lut_share_reader():
+    """``read`` of the benchmark's ``kernel.scan_scored_lut_share``."""
+    import importlib.util
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "chipbench"
+            / "metrics" / "kernel.scan_scored_lut_share.py")
+    spec = importlib.util.spec_from_file_location("lut_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def test_service_counts_lut_chunks(scored_world):
+    """A scored flush through the fused kernel counts the 8-row chunks
+    it LUT-scored and all of them; unscored serving counts neither, and
+    the benchmark's share reads a percentage of the scored flush."""
+    engine, corpus, queries, gt = scored_world
+    m = MutableAnnEngine(engine.sketcher, tail_rows=256)
+    m.add(corpus)
+    scored = AnnService(m, AnnServiceConfig(
+        top_k=3, scored=True, rerank_m=4, buckets=(4,), impl="pallas"))
+    plain = AnnService(m, AnnServiceConfig(top_k=3, buckets=(4,),
+                                           impl="pallas"))
+    for svc in (scored, plain):
+        for x in queries[:4]:
+            svc.submit(x)
+        svc.flush()
+    s = scored.stats
+    assert 0 < s["lut_chunks_scored"] <= s["lut_chunks"]
+    assert plain.stats["lut_chunks_scored"] == 0
+    assert plain.stats["lut_chunks"] == 0
+    share = _lut_share_reader()({"counters": dict(s)})
+    assert 0 < share <= 100
+    assert _lut_share_reader()({"counters": dict(plain.stats)}) is None
+
+
 def test_service_autotune_warmup_both_store_types(scored_world):
     """``autotune_warmup=True`` must survive warmup over both store
     shapes — CodeStore (words array) and SegmentLogStore (packed width
