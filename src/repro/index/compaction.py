@@ -20,8 +20,10 @@ Policy (size-tiered, greedy over the log):
 The rewrite gathers live rows on device (O(run) copy — the cost is
 proportional to what is rewritten, never the whole corpus) and emits a
 fully-live segment, so compaction both caps segment count and drops
-tombstoned rows. ``compact`` mutates the store in place and returns a
-stats dict.
+tombstoned rows. On a placed store (``SegmentLogStore(devices=...)``) the
+run's rows are joined on ``devices[0]``, and every segment whose log
+position changed then moves to the chip its new position names.
+``compact`` mutates the store in place and returns a stats dict.
 """
 from __future__ import annotations
 
@@ -71,15 +73,15 @@ def _rewrite_run(store: SegmentLogStore, run: list[Segment]) -> Segment:
     """Gather the run's live rows into one dense, fully-live segment."""
     rows_per = [seg.live_rows() for seg in run]
     n_new = int(sum(r.size for r in rows_per))
-    words = jnp.concatenate(
+    words = jnp.concatenate(store._gather(
         [jnp.take(seg.words, jnp.asarray(rows), axis=0)
-         for seg, rows in zip(run, rows_per) if rows.size]) \
+         for seg, rows in zip(run, rows_per) if rows.size])) \
         if n_new else jnp.zeros((0, store.n_words), jnp.uint32)
     hashes = None
     if store.band_spec is not None:
-        hashes = jnp.concatenate(
+        hashes = jnp.concatenate(store._gather(
             [jnp.take(seg.hashes, jnp.asarray(rows), axis=0)
-             for seg, rows in zip(run, rows_per) if rows.size]) \
+             for seg, rows in zip(run, rows_per) if rows.size])) \
             if n_new else jnp.zeros((0, store.band_spec.n_tables),
                                     jnp.uint32)
     ids = (np.concatenate([seg.ids[rows]
@@ -122,6 +124,7 @@ def compact(store: SegmentLogStore,
             if merged.length:       # an all-dead run just vanishes
                 new_sealed.append(merged)
         store.sealed = new_sealed
+        store._place()
         if runs:
             store.generation += 1
             # external ids survive a rewrite, so listeners (e.g. the

@@ -26,12 +26,22 @@ float scores — the same merge, float sentinel instead of -1. With
 top-m, then the LUT re-rank kernel over gathered candidates); both
 paths return bit-identical results — ``tests/test_kernel_conformance``
 holds them to it.
+
+Placement (``devices=...``, see ``index.segment_log``): each chunk puts
+the query's words and tables once on every chip that holds a live
+segment (span ``search.place``), dispatches each segment's kernel on its
+own chip in log order with no host sync, so the chips run at once, then
+moves the per-segment (vals, ids) to the merge chip ``devices[0]``
+(span ``search.gather``) and merges them there in log order: the same
+tie-break as one chip's log. ``last_shard_bytes`` counts what crossed
+chips.
 """
 from __future__ import annotations
 
 import time as _time
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.ann.bands import BandSpec, probe_hashes
@@ -43,7 +53,7 @@ from repro.rank.tables import RankTables, build_rank_tables
 from repro.core import packing as _packing
 from repro.core.sketch import CodedRandomProjection
 from repro.index.compaction import CompactionPolicy, compact
-from repro.index.segment_log import SegmentLogStore
+from repro.index.segment_log import SegmentLogStore, device_id
 from repro.index.snapshot import restore_index, save_index
 from repro.kernels import ops as _ops
 from repro.kernels import ref as _ref
@@ -66,13 +76,17 @@ class MutableAnnEngine:
     def __init__(self, sketcher: CodedRandomProjection, *,
                  band_spec: BandSpec = BandSpec(), tail_rows: int = 1024,
                  impl: str = "auto", store: SegmentLogStore = None,
-                 rank_tables: RankTables = None):
+                 rank_tables: RankTables = None, devices=None):
         self.sketcher = sketcher
         self._rank_tables = rank_tables
         if store is None:
             store = SegmentLogStore(sketcher.cfg.k, sketcher.spec.bits,
                                     band_spec=band_spec,
-                                    tail_rows=tail_rows, impl=impl)
+                                    tail_rows=tail_rows, impl=impl,
+                                    devices=devices)
+        elif devices is not None:
+            raise ValueError("devices place a new store; a given store "
+                             "keeps its own placement")
         if (store.k, store.bits) != (sketcher.cfg.k, sketcher.spec.bits):
             raise ValueError(
                 f"store k/bits {(store.k, store.bits)} != sketcher "
@@ -85,6 +99,9 @@ class MutableAnnEngine:
         # last ``search_codes``: the 8-row chunks it LUT-scored and all of
         # them (the oracle path appends none)
         self.last_lut_chunks = []
+        # bytes the last ``search_codes`` moved to another chip: the
+        # query put on each segment's chip, the results gathered to merge
+        self.last_shard_bytes = [0, 0]
 
     # -- mutation ------------------------------------------------------------
     @property
@@ -160,9 +177,10 @@ class MutableAnnEngine:
 
     @classmethod
     def restore(cls, sketcher: CodedRandomProjection, directory: str,
-                step: int = None) -> "MutableAnnEngine":
-        """Engine over a restored store (latest snapshot, or ``step``)."""
-        return cls(sketcher, store=restore_index(directory, step))
+                step: int = None, devices=None) -> "MutableAnnEngine":
+        """Engine over a restored store (latest snapshot, or ``step``),
+        placed over ``devices`` when given."""
+        return cls(sketcher, store=restore_index(directory, step, devices))
 
     # -- search --------------------------------------------------------------
     @property
@@ -226,6 +244,7 @@ class MutableAnnEngine:
                              "mode='exact')")
         q = q_codes.shape[0]
         self.last_lut_chunks = []
+        self.last_shard_bytes = [0, 0]
         if q == 0 or self.store.n_live == 0:
             return (jnp.full((q, cfg.top_k), -1, jnp.int32),
                     jnp.full((q, cfg.top_k), -1.0, jnp.float32))
@@ -258,20 +277,25 @@ class MutableAnnEngine:
                 self.rank_tables, q_codes, cfg.table_dtype)
         elif cfg.scored:
             q_tables = self.rank_tables.query_tables(q_codes)
+        live = [(i, seg) for i, seg in enumerate(self.store.segments())
+                if seg.live]
+        query = (q_words, q_tables, scales, qh)
+        placed = (self._place_query(query, live)
+                  if self.store.devices is not None else None)
         vals_l, ids_l = [], []
         # the span syncs below only block under a *deep* tracer
         # (profiling); with no tracer, or a shallow per-request
         # RequestTrace, the eager segment loop keeps its async pipeline
-        for i, seg in enumerate(self.store.segments()):
-            if seg.live == 0:
-                continue
+        for i, seg in live:
+            qw, qt, qs, qp = query if placed is None else placed[seg.device]
             if fused:
                 m = cfg.resolve_m(seg.cap)
                 with span("search.fused", segment=i, rows=seg.cap,
-                          m=m, top_k=cfg.top_k) as sp:
+                          m=m, top_k=cfg.top_k,
+                          device=device_id(seg.device)) as sp:
                     vals, rows = _ops.fused_scored_topk_masked(
-                        q_words, q_tables, seg.words, seg.valid_dev(),
-                        bits, k, m, cfg.top_k, scales=scales,
+                        qw, qt, seg.words, seg.valid_dev(),
+                        bits, k, m, cfg.top_k, scales=qs,
                         impl=cfg.impl, lut_chunks=self.last_lut_chunks)
                     sp.sync(vals)
                 ext = jnp.take(seg.ids_dev(),
@@ -281,17 +305,18 @@ class MutableAnnEngine:
                 continue
             top = cfg.resolve_m(seg.cap) if cfg.scored else cfg.top_k
             with span("search.coarse", mode=cfg.mode, segment=i,
-                      rows=seg.cap) as sp:
+                      rows=seg.cap, device=device_id(seg.device)) as sp:
                 if cfg.mode == "exact":
                     vals, rows = _ops.packed_topk_masked(
-                        q_words, seg.words, seg.valid_dev(), bits, k,
+                        qw, seg.words, seg.valid_dev(), bits, k,
                         top, impl=cfg.impl)
                 else:
                     counts = _ops.packed_collision_counts(
-                        q_words, seg.words, bits, k, impl=cfg.impl)
-                    coarse = _coarse_band_scores(qh, seg.hashes)
-                    live = _packing.unpack_bitmask(seg.valid_dev(), seg.cap)
-                    counts = jnp.where(live[None, :]
+                        qw, seg.words, bits, k, impl=cfg.impl)
+                    coarse = _coarse_band_scores(qp, seg.hashes)
+                    live_rows = _packing.unpack_bitmask(seg.valid_dev(),
+                                                        seg.cap)
+                    counts = jnp.where(live_rows[None, :]
                                        & (coarse >= cfg.min_bands),
                                        counts, -1)
                     vals, rows = _ref.topk_stable_ref(counts, top)
@@ -301,19 +326,49 @@ class MutableAnnEngine:
                           top_k=cfg.top_k) as sp:
                     rows, vals = lut_rerank_stage(
                         self.rank_tables, q_codes, rows, seg.words,
-                        cfg.top_k, impl=cfg.impl, q_tables=q_tables)
+                        cfg.top_k, impl=cfg.impl, q_tables=qt)
                     sp.sync(vals)
             ext = jnp.take(seg.ids_dev(),
                            jnp.clip(rows, 0, seg.cap - 1), axis=0)
             ids_l.append(jnp.where(rows < 0, -1, ext))
             vals_l.append(vals)
+        if placed is not None:
+            vals_l, ids_l = self._gather_results(vals_l, ids_l)
         vals, ids = merge_topk(vals_l, ids_l, cfg.top_k)
         if cfg.scored:
             return ids, rho_scored(self.rank_tables, ids, vals)
         return ids, self._rho(vals)
+
+    def _place_query(self, query, live):
+        """The chunk's query arrays put once on each chip that holds a
+        live segment -> {device: query}; bytes that cross chips count
+        into ``last_shard_bytes[0]``."""
+        chips = list(dict.fromkeys(seg.device for _, seg in live))
+        with span("search.place", chips=len(chips)):
+            placed = {}
+            for dev in chips:
+                placed[dev] = jax.device_put(query, dev)
+                self.last_shard_bytes[0] += _bytes_off(query, dev)
+        return placed
+
+    def _gather_results(self, vals_l, ids_l):
+        """Every segment's (vals, ids) moved to the merge chip
+        ``devices[0]``, in log order; bytes that cross chips count into
+        ``last_shard_bytes[1]``."""
+        merge = self.store.devices[0]
+        moved = sum(merge not in v.devices() for v in vals_l)
+        with span("search.gather", segments=moved):
+            self.last_shard_bytes[1] += _bytes_off((vals_l, ids_l), merge)
+            return jax.device_put((vals_l, ids_l), merge)
 
     def _rho(self, counts):
         """Collision counts -> rho_hat (paper estimator); empty slots
         (count < 0) surface as rho = -1."""
         rho = self.sketcher._estimator(counts / self.sketcher.cfg.k)
         return jnp.where(counts < 0, -1.0, rho)
+
+
+def _bytes_off(tree, device) -> int:
+    """Bytes of the arrays in ``tree`` that are not on ``device``."""
+    return sum(x.nbytes for x in jax.tree.leaves(tree)
+               if device not in x.devices())

@@ -28,6 +28,14 @@ live rows in row order, then the tail — defines search tie-breaking, and
 is exactly the row order of a fresh ``CodeStore`` built from
 ``live_codes()``: the bit-exactness contract the tests enforce.
 
+Placement: a store given ``devices`` keeps segment ``s`` of the log
+(sealed or tail) whole on ``devices[s % len(devices)]``: its words and
+hashes, the device copies of its validity and ids, and the chunks the
+tail is written with. Compaction and restore keep the rule by moving any
+segment whose log position changed; searches run each segment on its
+own chip and merge on ``devices[0]`` (``repro.index.engine``). Without
+``devices`` every array stays where JAX puts it by default.
+
 Lifecycle ops live beside this module: ``compaction`` (size-tiered merge
 that drops tombstones), ``snapshot`` (durability via ``repro.checkpoint``).
 """
@@ -75,19 +83,22 @@ class Segment:
     the tail, rows past ``length`` are unwritten (their validity bits are
     0, so search can treat the full buffer as the segment). ``valid`` is
     the host-authoritative packed bitmask; ``valid_dev``/``ids_dev`` are
-    demand-built device copies, dropped on mutation.
+    demand-built device copies, dropped on mutation. ``device`` is the
+    chip a placed store keeps all of them on (None: unplaced).
     """
 
     __slots__ = ("words", "hashes", "ids", "valid", "live", "length",
-                 "_valid_dev", "_ids_dev")
+                 "device", "_valid_dev", "_ids_dev")
 
-    def __init__(self, words, hashes, ids, valid, live, length):
+    def __init__(self, words, hashes, ids, valid, live, length,
+                 device=None):
         self.words = words            # uint32 [cap, W] device
         self.hashes = hashes          # uint32 [cap, L] device | None
         self.ids = ids                # int64 [cap] host
         self.valid = valid            # uint32 [ceil(cap/32)] host bitmask
         self.live = live              # live-row count
         self.length = length          # written rows (== cap once sealed)
+        self.device = device          # jax.Device | None (unplaced)
         self._valid_dev = None
         self._ids_dev = None
 
@@ -100,14 +111,15 @@ class Segment:
         """Device copy of the packed validity bitmask, uint32
         [ceil(cap/32)] (cached until the next mutation)."""
         if self._valid_dev is None:
-            self._valid_dev = jnp.asarray(self.valid)
+            self._valid_dev = jnp.asarray(self.valid, device=self.device)
         return self._valid_dev
 
     def ids_dev(self):
         """Device copy of the external ids, int32 [cap] (-1 =
         unwritten slot; cached until the next mutation)."""
         if self._ids_dev is None:
-            self._ids_dev = jnp.asarray(self.ids.astype(np.int32))
+            self._ids_dev = jnp.asarray(self.ids.astype(np.int32),
+                                        device=self.device)
         return self._ids_dev
 
     def live_rows(self) -> np.ndarray:
@@ -121,15 +133,31 @@ class Segment:
         self.live -= 1
         self._valid_dev = None
 
+    def move_to(self, device):
+        """Put the segment's device arrays on ``device`` (its cached
+        device copies are rebuilt there on demand)."""
+        self.words = jax.device_put(self.words, device)
+        if self.hashes is not None:
+            self.hashes = jax.device_put(self.hashes, device)
+        self.device = device
+        self._valid_dev = None
+        self._ids_dev = None
 
-def _empty_segment(cap: int, n_words: int, n_tables) -> Segment:
+
+def device_id(device):
+    """A span attribute naming a chip: its id, or None when unplaced."""
+    return None if device is None else device.id
+
+
+def _empty_segment(cap: int, n_words: int, n_tables,
+                   device=None) -> Segment:
     return Segment(
-        words=jnp.zeros((cap, n_words), jnp.uint32),
-        hashes=(jnp.zeros((cap, n_tables), jnp.uint32)
+        words=jnp.zeros((cap, n_words), jnp.uint32, device=device),
+        hashes=(jnp.zeros((cap, n_tables), jnp.uint32, device=device)
                 if n_tables else None),
         ids=np.full(cap, -1, np.int64),
         valid=np.zeros(_packing.bitmask_width(cap), np.uint32),
-        live=0, length=0)
+        live=0, length=0, device=device)
 
 
 class SegmentLogStore:
@@ -137,15 +165,20 @@ class SegmentLogStore:
 
     All mutators bump ``generation`` (result-cache invalidation hook for
     the serving layer). The store holds *codes*; vector encoding lives in
-    ``repro.index.engine.MutableAnnEngine``.
+    ``repro.index.engine.MutableAnnEngine``. ``devices`` (a sequence of
+    ``jax.Device``) places segment ``s`` on ``devices[s % len(devices)]``
+    (module docstring); None leaves placement to JAX.
     """
 
     def __init__(self, k: int, bits: int, *, band_spec: BandSpec = None,
                  tail_rows: int = 1024, impl: str = "auto",
-                 registry: MetricsRegistry = None):
+                 registry: MetricsRegistry = None, devices=None):
         if tail_rows % 32:
             raise ValueError(f"tail_rows must be a multiple of 32, "
                              f"got {tail_rows}")
+        if devices is not None and not len(devices):
+            raise ValueError("devices must name at least one device")
+        self.devices = tuple(devices) if devices is not None else None
         self.k = k
         self.bits = bits
         self.band_spec = band_spec.validate(k) if band_spec else None
@@ -183,7 +216,32 @@ class SegmentLogStore:
     def _new_tail(self) -> Segment:
         return _empty_segment(
             self.tail_rows, self.n_words,
-            self.band_spec.n_tables if self.band_spec else 0)
+            self.band_spec.n_tables if self.band_spec else 0,
+            device=self.device_at(len(self.sealed)))
+
+    # -- placement -----------------------------------------------------------
+    def device_at(self, pos: int):
+        """The chip that segment ``pos`` of the log lives on (None when
+        the store is unplaced)."""
+        if self.devices is None:
+            return None
+        return self.devices[pos % len(self.devices)]
+
+    def _place(self):
+        """Move every segment whose log position changed (compaction,
+        restore) onto the chip that position names."""
+        if self.devices is None:
+            return
+        for pos, seg in enumerate(self.segments()):
+            if seg.device != self.device_at(pos):
+                seg.move_to(self.device_at(pos))
+
+    def _gather(self, arrays: list) -> list:
+        """Arrays of several segments, put on one chip (``devices[0]``)
+        so they can be joined; an unplaced store returns them as-is."""
+        if self.devices is None:
+            return arrays
+        return jax.device_put(arrays, self.devices[0])
 
     # -- geometry ------------------------------------------------------------
     @property
@@ -244,7 +302,7 @@ class SegmentLogStore:
             rows.append(seg.words[row])
         if not rows:
             return np.zeros((0, self.k), np.int32)
-        words = jnp.stack(rows)
+        words = jnp.stack(self._gather(rows))
         return np.asarray(
             _packing.unpack_codes(words, self.bits, self.k), np.int32)
 
@@ -338,11 +396,15 @@ class SegmentLogStore:
         chunk = jax.lax.dynamic_slice_in_dim(words, pos, t, 0)
         if tp > t:      # zero rows land on not-yet-valid slots
             chunk = jnp.pad(chunk, ((0, tp - t), (0, 0)))
+        if tail.device is not None:
+            chunk = jax.device_put(chunk, tail.device)
         tail.words = _write_rows(tail.words, chunk, start)
         if hashes is not None:
             hc = jax.lax.dynamic_slice_in_dim(hashes, pos, t, 0)
             if tp > t:
                 hc = jnp.pad(hc, ((0, tp - t), (0, 0)))
+            if tail.device is not None:
+                hc = jax.device_put(hc, tail.device)
             tail.hashes = _write_rows(tail.hashes, hc, start)
         with span("store.id_map", rows=t):
             rows = np.arange(start, start + t)
@@ -360,7 +422,8 @@ class SegmentLogStore:
     def _seal_tail(self):
         """The full tail becomes a sealed segment as-is (no copy: the id
         map keys on the Segment object, which just moves lists)."""
-        with span("store.seal", segment=len(self.sealed)):
+        with span("store.seal", segment=len(self.sealed),
+                  device=device_id(self.tail.device)):
             self.sealed.append(self.tail)
             self.tail = self._new_tail()
             self._c_seals.inc()
@@ -424,7 +487,7 @@ class SegmentLogStore:
                  if (rows := seg.live_rows()).size]
         if not parts:
             return jnp.zeros((0, self.n_words), jnp.uint32)
-        return jnp.concatenate(parts)
+        return jnp.concatenate(self._gather(parts))
 
     def live_codes(self):
         """Unpacked live rows [n_live, k] int32 (fresh-build oracle)."""

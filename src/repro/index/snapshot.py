@@ -13,6 +13,8 @@ store serves bit-identical results (including tie order and tombstones).
 The tail is snapshotted at full buffer size with its ``length`` in the
 metadata, so a restored index resumes ingestion exactly where it stopped;
 ``next_id`` round-trips so ids are never reused after restart.
+Placement is not part of a snapshot: ``restore_index(..., devices=...)``
+places the restored segments by the store's rule.
 """
 from __future__ import annotations
 
@@ -71,10 +73,12 @@ def _like_from_manifest(manifest: dict) -> dict:
     return like
 
 
-def restore_index(directory: str, step: int = None) -> SegmentLogStore:
+def restore_index(directory: str, step: int = None,
+                  devices=None) -> SegmentLogStore:
     """Rebuild a ``SegmentLogStore`` from a snapshot (latest step when
-    ``step`` is None). Self-describing: structure comes from the
-    checkpoint manifest, geometry/id state from the metadata leaf."""
+    ``step`` is None), placed over ``devices`` when given. Self-describing:
+    structure comes from the checkpoint manifest, geometry/id state from
+    the metadata leaf."""
     if step is None:
         step = latest_step(directory)
         if step is None:
@@ -91,7 +95,8 @@ def restore_index(directory: str, step: int = None) -> SegmentLogStore:
     band = (BandSpec(n_tables=meta["band"][0], band_width=meta["band"][1])
             if meta["band"] else None)
     store = SegmentLogStore(meta["k"], meta["bits"], band_spec=band,
-                            tail_rows=meta["tail_rows"], impl=meta["impl"])
+                            tail_rows=meta["tail_rows"], impl=meta["impl"],
+                            devices=devices)
     n_segs = meta["n_segments"]
     for i in range(n_segs):
         is_tail = i == n_segs - 1
@@ -112,6 +117,7 @@ def restore_index(directory: str, step: int = None) -> SegmentLogStore:
             store.tail = seg
         else:
             store.sealed.append(seg)
+    store._place()
     store.next_id = meta["next_id"]
     store.generation += 1
     return store

@@ -35,7 +35,9 @@ start of the flush slice that answers a ticket
 (``serve.queue_wait_s``), cache hit/miss/eviction/invalidation and
 warmup-compile counters, the 8-row chunks the fused scored kernel
 LUT-scored and all of its chunks (``serve.lut_chunks_scored``,
-``serve.lut_chunks``; fetched with the batch's ids), and a
+``serve.lut_chunks``; fetched with the batch's ids), the bytes a placed
+engine moved to another chip (``serve.shard_put_bytes`` for the query,
+``serve.shard_gather_bytes`` for the results; 0 unplaced), and a
 padding-waste gauge. The old ad-hoc
 ``stats`` dict survives as a read-only compat property derived from the
 counters. Spans on the profiler's clock mark each stage of the request
@@ -153,6 +155,8 @@ class AnnService:
         self._c_wait = reg.counter("serve.queue_wait_s")
         self._c_lut_scored = reg.counter("serve.lut_chunks_scored")
         self._c_lut_chunks = reg.counter("serve.lut_chunks")
+        self._c_shard_put = reg.counter("serve.shard_put_bytes")
+        self._c_shard_gather = reg.counter("serve.shard_gather_bytes")
         self._h_flush = reg.histogram("serve.flush_s")
         self._h_age = reg.histogram("serve.ticket_age_s")
         self._h_classify = reg.histogram("serve.classify_s")
@@ -244,6 +248,8 @@ class AnnService:
             "queue_wait_s": self._c_wait.value,
             "lut_chunks_scored": self._c_lut_scored.value,
             "lut_chunks": self._c_lut_chunks.value,
+            "shard_put_bytes": self._c_shard_put.value,
+            "shard_gather_bytes": self._c_shard_gather.value,
         })
 
     # -- request path --------------------------------------------------------
@@ -492,6 +498,7 @@ class AnnService:
                  self._c_batches, self._c_padded, self._c_classified,
                  self._c_flush_err, self._c_classify_err, self._c_wait,
                  self._c_lut_scored, self._c_lut_chunks,
+                 self._c_shard_put, self._c_shard_gather,
                  self._g_waste, self.sampler, self.quality)
         eng_quality = getattr(self.engine, "quality", None)
         self._h_flush = reg.histogram("serve.probe.flush_s")
@@ -508,6 +515,9 @@ class AnnService:
         self._c_wait = reg.counter("serve.probe.queue_wait_s")
         self._c_lut_scored = reg.counter("serve.probe.lut_chunks_scored")
         self._c_lut_chunks = reg.counter("serve.probe.lut_chunks")
+        self._c_shard_put = reg.counter("serve.probe.shard_put_bytes")
+        self._c_shard_gather = reg.counter(
+            "serve.probe.shard_gather_bytes")
         self._g_waste = reg.gauge("serve.probe.padding_waste")
         self.sampler = _PROBE_SAMPLER
         self.quality = None
@@ -522,6 +532,7 @@ class AnnService:
              self._c_batches, self._c_padded, self._c_classified,
              self._c_flush_err, self._c_classify_err, self._c_wait,
              self._c_lut_scored, self._c_lut_chunks,
+             self._c_shard_put, self._c_shard_gather,
              self._g_waste, self.sampler, self.quality) = saved
             if eng_quality is not None:
                 self.engine.quality = eng_quality
@@ -646,6 +657,7 @@ class AnnService:
                                       fused=cfg.fused,
                                       table_dtype=cfg.table_dtype))
                 chunks = getattr(self.engine, "last_lut_chunks", ())
+                shard = getattr(self.engine, "last_shard_bytes", (0, 0))
             with span("serve.fetch"):
                 if miss:
                     # host transfer is the device sync for this batch
@@ -656,6 +668,8 @@ class AnnService:
                             [np.asarray(c) for c in chunks], axis=0).tolist()
                         self._c_lut_scored.inc(scored)
                         self._c_lut_chunks.inc(total)
+                    self._c_shard_put.inc(shard[0])
+                    self._c_shard_gather.inc(shard[1])
                     self.flight.record(
                         "serve.search", t_batch, time.perf_counter(),
                         t_queue=min(self._submit_ts.get(t, t_batch)

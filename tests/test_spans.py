@@ -14,15 +14,16 @@ import jax.numpy as jnp
 
 from repro.core.sketch import CodedRandomProjection, SketchConfig
 from repro.index import MutableAnnEngine
-from repro.obs import install_gc_spans, span, tracing_active
+from repro.obs import Tracer, install_gc_spans, span, tracing_active
 from repro.serve.ann_service import AnnService, AnnServiceConfig
 
 D, K = 16, 16
 
 
-def _service(tail_rows=64, **cfg):
+def _service(tail_rows=64, devices=None, **cfg):
     crp = CodedRandomProjection(SketchConfig(k=K, scheme="2bit", w=0.75), D)
-    eng = MutableAnnEngine(crp, band_spec=None, tail_rows=tail_rows)
+    eng = MutableAnnEngine(crp, band_spec=None, tail_rows=tail_rows,
+                           devices=devices)
     return AnnService(eng, AnnServiceConfig(top_k=3, buckets=(1, 4),
                                             **cfg))
 
@@ -103,6 +104,48 @@ def test_flush_spans_nest_inside_serve_flush(tmp_path):
     starts = [f[0][1] for f in found]
     assert starts == sorted(starts)             # in pipeline order
     assert prof.inside(found[3][0], "search.coarse")   # one per segment
+
+
+def test_placed_search_opens_place_and_gather():
+    """A placed engine puts the query on its segments' chips
+    (``search.place``) and gathers their results (``search.gather``)
+    inside ``engine.search``; an unplaced one opens neither."""
+    found = {}
+    for placed in (False, True):
+        svc = _service(cache_size=0,
+                       devices=jax.devices()[:1] if placed else None)
+        svc.bulk_load(_rows(150), chunk_rows=32)    # 2 sealed + tail
+        with Tracer() as tr:
+            for row in _rows(3, seed=1):
+                svc.submit(row)
+            svc.flush()
+        (search,) = [e for e in tr.events if e["name"] == "engine.search"]
+        inside = [e["name"] for e in tr.events
+                  if search["ts"] <= e["ts"]
+                  and e["ts"] + e["dur"] <= search["ts"] + search["dur"]]
+        found[placed] = [inside.count(name) for name in
+                         ("search.place", "search.gather", "search.coarse")]
+    assert found == {False: [0, 0, 3], True: [1, 1, 3]}
+
+
+def test_segment_spans_name_their_device():
+    """``search.fused``, ``search.coarse`` and ``store.seal`` carry the
+    chip of their segment (None when unplaced)."""
+    dev = jax.devices()[0]
+    for devices, scored in ((None, False), ([dev], False), ([dev], True)):
+        svc = _service(cache_size=0, scored=scored, devices=devices)
+        with Tracer() as tr:
+            svc.bulk_load(_rows(80), chunk_rows=16)
+            svc.submit(_rows(1, seed=3)[0])
+            svc.flush()
+        seg = "search.fused" if scored else "search.coarse"
+        spans = [e for e in tr.events if e["name"] in (seg, "store.seal")]
+        assert [e["name"] for e in spans].count(seg) == 2
+        assert [e["name"] for e in spans].count("store.seal") == 1
+        want = None if devices is None else dev.id
+        assert all(e["args"]["device"] == want for e in spans)
+    placed = [e for e in tr.events if e["name"] == "search.place"]
+    assert [e["args"]["chips"] for e in placed] == [1]
 
 
 def test_bulk_load_spans_nest_inside_store_append(tmp_path):
