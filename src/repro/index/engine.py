@@ -95,10 +95,11 @@ class MutableAnnEngine:
         self.band_spec = store.band_spec
         self._coder = QueryCoder(sketcher)
         self.quality = None       # obs.quality.QualityMonitors, if attached
-        # one int32 [2] device array per fused scored kernel call of the
-        # last ``search_codes``: the 8-row chunks it LUT-scored and all of
-        # them (the oracle path appends none)
-        self.last_lut_chunks = []
+        # one int32 [4] device array per fused scored kernel call of the
+        # last ``search_codes``: the 8-row chunks it LUT-scored, all of
+        # them, the histogram bins it visited, all of them (the oracle
+        # path appends none)
+        self.last_kernel_counters = []
         # bytes the last ``search_codes`` moved to another chip: the
         # query put on each segment's chip, the results gathered to merge
         self.last_shard_bytes = [0, 0]
@@ -243,7 +244,7 @@ class MutableAnnEngine:
                              "scored path (scored=True, fused=True, "
                              "mode='exact')")
         q = q_codes.shape[0]
-        self.last_lut_chunks = []
+        self.last_kernel_counters = []
         self.last_shard_bytes = [0, 0]
         if q == 0 or self.store.n_live == 0:
             return (jnp.full((q, cfg.top_k), -1, jnp.int32),
@@ -296,7 +297,7 @@ class MutableAnnEngine:
                     vals, rows = _ops.fused_scored_topk_masked(
                         qw, qt, seg.words, seg.valid_dev(),
                         bits, k, m, cfg.top_k, scales=qs,
-                        impl=cfg.impl, lut_chunks=self.last_lut_chunks)
+                        impl=cfg.impl, counters=self.last_kernel_counters)
                     sp.sync(vals)
                 ext = jnp.take(seg.ids_dev(),
                                jnp.clip(rows, 0, seg.cap - 1), axis=0)

@@ -23,11 +23,25 @@ Two sweeps over the corpus stream (grid minor axis runs 0..2*NT-1; VMEM
 scratch persists across the minor axis for a fixed query tile):
 
 sweep A (j < NT)
-    XOR/popcount counts per tile, accumulate A(c) for c in 0..k into a
-    [k+1, bq] VMEM histogram (tiles are transposed, rows on sublanes and
-    queries on lanes, like every streaming kernel here). At the phase boundary (j == NT) the
-    histogram inverts into (t, quota) with a min/max reduction — no
-    gather, no sort.
+    XOR/popcount counts per tile, accumulate A(c) into a [k+1, bq] VMEM
+    histogram (tiles are transposed, rows on sublanes and queries on
+    lanes, like every streaming kernel here) — but only over the bin
+    window [lo, hi) that can still change the inversion. ``hi`` is the
+    tile's largest count: bins at or above it gain nothing from the
+    tile. ``lo`` is the smallest threshold any valid query lane has so
+    far (the least c with A(c) < m over lanes < q_valid; 0 at the first
+    tile), kept in SMEM and raised after each tile. Exact: A only grows
+    as tiles arrive, so a bin below ``lo`` already held m or more in
+    every valid lane when it stopped being updated, and stays >= m both
+    as stored and as it truly is — the inversion's ``A(c) < m`` is false
+    there either way — while every bin from ``lo`` up is exact. So t,
+    A(t) and the quota are those of the full histogram. Padded lanes
+    leave ``lo`` alone; their threshold is forced past k anyway. On
+    Gaussian-like count spreads the window is a few dozen of the k+1
+    bins once a segment's first tiles have set ``lo``; a tile that holds
+    a near-duplicate opens it up to that count. At the phase boundary
+    (j == NT) the histogram inverts into (t, quota) with a min/max
+    reduction — no gather, no sort.
 
 sweep B (j >= NT)
     Recompute the tile's counts (cheaper than writing [Q, N] to HBM and
@@ -48,10 +62,11 @@ sweep B (j >= NT)
     change nothing. The survivors' scores, and the order in which they
     merge, are those of scoring every row.
 
-The kernel counts the chunks it scored, per query tile, in an SMEM
-scalar; ``lut_chunks=True`` returns the total with the number of all
-chunks. Padded query lanes take a threshold no count reaches, so they
-flag no chunk.
+The kernel counts, per query tile in SMEM scalars, the chunks it
+scored and the histogram bins sweep A visited; ``counters=True``
+returns both totals, each with the number of all chunks or bins.
+Padded query lanes take a threshold no count reaches, so they flag no
+chunk.
 
 Scoring paths (``packed_lut.lut_chunk``): float tables upcast to float32
 before the kernel and accumulate in (word, field) order (bit-identical
@@ -113,6 +128,7 @@ def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
     keys_ref, above_ref, thr_ref, quota_ref, ties_ref = (
         next(it), next(it), next(it), next(it), next(it))
     rk_ref, ri_ref, scored_ref = next(it), next(it), next(it)
+    lo_ref, bins_ref = next(it), next(it)
 
     i, j = pl.program_id(0), pl.program_id(1)
     tile = jax.lax.rem(j, nt)
@@ -127,6 +143,8 @@ def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
     @pl.when(j == 0)
     def _init_hist():
         above_ref[...] = jnp.zeros_like(above_ref)
+        lo_ref[0, 0] = 0
+        bins_ref[0, 0] = 0
 
     @pl.when(j < nt)
     def _sweep_a():
@@ -138,7 +156,17 @@ def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
                 keepdims=True)
             return carry
 
-        jax.lax.fori_loop(0, k + 1, bin_, 0)
+        # only bins in [lo, hi) can still move a valid lane's threshold
+        lo, hi = lo_ref[0, 0], jnp.max(keys_ref[...])
+        jax.lax.fori_loop(lo, hi, bin_, 0)
+        bins_ref[0, 0] += jnp.maximum(hi - lo, 0)
+        # bins below lo are stale but >= m in every valid lane, so the
+        # least c with A(c) < m over valid lanes never falls below lo
+        a = above_ref[...]                                # [k+1, bq]
+        cidx = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+        lane = i * bq + jax.lax.broadcasted_iota(jnp.int32, a.shape, 1)
+        lo_ref[0, 0] = jnp.min(
+            jnp.where((a < rerank_m) & (lane < q_valid), cidx, k))
 
     @pl.when(j == nt)
     def _invert():
@@ -208,7 +236,8 @@ def _fused_scored_kernel(*refs, bits: int, k: int, rerank_m: int,
     def _finalize():
         ok_ref[...] = rk_ref[...]
         oi_ref[...] = ri_ref[...]
-        oc_ref[...] = jnp.full(oc_ref.shape, scored_ref[0, 0], jnp.int32)
+        row = jax.lax.broadcasted_iota(jnp.int32, oc_ref.shape, 0)
+        oc_ref[...] = jnp.where(row == 0, scored_ref[0, 0], bins_ref[0, 0])
 
 
 def _fused_scored_call(q_words, q_tables, words_db, valid_words, scales,
@@ -229,7 +258,7 @@ def _fused_scored_call(q_words, q_tables, words_db, valid_words, scales,
     if n == 0:
         return (jnp.full((qn, top_k), _NEG_INF, jnp.float32),
                 jnp.full((qn, top_k), -1, jnp.int32),
-                jnp.zeros((2,), jnp.int32))
+                jnp.zeros((4,), jnp.int32))
     qT = _pad(q_words, block_q, 0).T                      # [W, Qp]
     tT = transposed_tables(q_tables, block_q)             # [F*P, Qp]
     dbp = _pad(words_db, block_n, 0)
@@ -254,38 +283,42 @@ def _fused_scored_call(q_words, q_tables, words_db, valid_words, scales,
         n_valid=n, q_valid=qn, block_n=block_n, nt=nt,
         has_mask=valid_words is not None, has_scales=scales is not None)
     out_specs, run_scratch = topk_out_specs(t_rows, block_q)
-    keys, ids, scored = pl.pallas_call(
+    keys, ids, tallies = pl.pallas_call(
         kernel,
         grid=(qm // block_q, 2 * nt),
         in_specs=in_specs,
         out_specs=out_specs + [
-            pl.BlockSpec((1, block_q), lambda i, j: (0, i))],
+            pl.BlockSpec((2, block_q), lambda i, j: (0, i))],
         out_shape=[jax.ShapeDtypeStruct((t_rows, qm), jnp.int32)] * 2
-        + [jax.ShapeDtypeStruct((1, qm), jnp.int32)],
+        + [jax.ShapeDtypeStruct((2, qm), jnp.int32)],
         scratch_shapes=[
             pltpu.VMEM((block_n, block_q), jnp.int32),
             pltpu.VMEM((k + 1, block_q), jnp.int32),
             pltpu.VMEM((1, block_q), jnp.int32),
             pltpu.VMEM((1, block_q), jnp.int32),
             pltpu.VMEM((1, block_q), jnp.int32),
-        ] + run_scratch + [pltpu.SMEM((1, 1), jnp.int32)],
+        ] + run_scratch + [pltpu.SMEM((1, 1), jnp.int32)] * 3,
         interpret=interpret,
     )(*inputs)
-    # every lane of a query tile carries that tile's count
-    chunks = jnp.stack([jnp.sum(scored[0, ::block_q]),
-                        jnp.int32(qm // block_q * (nm // _LUT_ROWS))])
-    return key_float(keys[:top_k, :qn].T), ids[:top_k, :qn].T, chunks
+    # every lane of a query tile carries that tile's counts: chunks
+    # scored in row 0, bins visited in row 1
+    tiles = qm // block_q
+    counters = jnp.stack([jnp.sum(tallies[0, ::block_q]),
+                          jnp.int32(tiles * (nm // _LUT_ROWS)),
+                          jnp.sum(tallies[1, ::block_q]),
+                          jnp.int32(tiles * nt * (k + 1))])
+    return key_float(keys[:top_k, :qn].T), ids[:top_k, :qn].T, counters
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "k", "rerank_m", "top_k", "block_q",
-                     "block_n", "interpret", "lut_chunks"))
+                     "block_n", "interpret", "counters"))
 def fused_scored_topk_pallas(q_words, q_tables, words_db, bits: int,
                              k: int, rerank_m: int, top_k: int, *,
                              scales=None, block_q: int = 128,
                              block_n: int = 512, interpret: bool = False,
-                             lut_chunks: bool = False):
+                             counters: bool = False):
     """Single-pass scored search: q_words uint32 [Q, W], q_tables float
     or int8 [Q, F*P], words_db uint32 [N, W] -> (scores f32 [Q, top_k],
     corpus ids int32 [Q, top_k]).
@@ -297,35 +330,36 @@ def fused_scored_topk_pallas(q_words, q_tables, words_db, bits: int,
     ``ref.fused_scored_topk_ref`` (scores, lowest-id ties, (-inf, -1)
     sentinel padding when candidates run out).
 
-    ``lut_chunks=True`` appends int32 [2]: the 8-row chunks the kernel
-    LUT-scored, summed over query tiles, and all of them (query tiles x
-    padded rows / 8).
+    ``counters=True`` appends int32 [4], each summed over query tiles:
+    the 8-row chunks the kernel LUT-scored, all of them (query tiles x
+    padded rows / 8), the histogram bins sweep A visited, and all of
+    them (query tiles x corpus tiles x (k + 1)).
     """
     out = _fused_scored_call(q_words, q_tables, words_db, None, scales,
                              bits, k, rerank_m, top_k, block_q, block_n,
                              interpret)
-    return out if lut_chunks else out[:2]
+    return out if counters else out[:2]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("bits", "k", "rerank_m", "top_k", "block_q",
-                     "block_n", "interpret", "lut_chunks"))
+                     "block_n", "interpret", "counters"))
 def fused_scored_topk_masked_pallas(q_words, q_tables, words_db,
                                     valid_words, bits: int, k: int,
                                     rerank_m: int, top_k: int, *,
                                     scales=None, block_q: int = 128,
                                     block_n: int = 512,
                                     interpret: bool = False,
-                                    lut_chunks: bool = False):
+                                    counters: bool = False):
     """``fused_scored_topk_pallas`` over live rows only: ``valid_words``
     uint32 [ceil(N/32)] packed bitmask (``packing.pack_bitmask``
     layout). Tombstoned rows take count -1 before the survivor rule, so
     they can neither survive nor displace a live tie; the mask is data,
     not shape — deletes never recompile. Bit-exact vs
-    ``ref.fused_scored_topk_masked_ref``; ``lut_chunks`` as there.
+    ``ref.fused_scored_topk_masked_ref``; ``counters`` as there.
     """
     out = _fused_scored_call(q_words, q_tables, words_db, valid_words,
                              scales, bits, k, rerank_m, top_k, block_q,
                              block_n, interpret)
-    return out if lut_chunks else out[:2]
+    return out if counters else out[:2]

@@ -320,14 +320,14 @@ def fused_scored_topk(q_words, q_tables, words_db, bits: int, k: int,
 def fused_scored_topk_masked(q_words, q_tables, words_db, valid_words,
                              bits: int, k: int, rerank_m: int, top_k: int,
                              scales=None, impl: str = "auto",
-                             lut_chunks: Optional[list] = None,
+                             counters: Optional[list] = None,
                              **block_kwargs):
     """``fused_scored_topk`` over live rows only (packed row-validity
     bitmask) — the mutable-index segment path; all-dead segments return
-    pure (-inf, -1) sentinels. Given a list as ``lut_chunks``, the
-    kernel appends its int32 [2] (8-row chunks LUT-scored, all 8-row
-    chunks); the oracle scores only the candidates and appends
-    nothing."""
+    pure (-inf, -1) sentinels. Given a list as ``counters``, the kernel
+    appends its int32 [4] (8-row chunks LUT-scored, all 8-row chunks,
+    histogram bins visited, all bins); the oracle scores only the
+    candidates and appends nothing."""
     _rec("fused_scored_topk_masked", q_words, q_tables, words_db,
          q=q_words.shape[0], n=words_db.shape[0], w=q_words.shape[1],
          t=q_tables.shape[1], k=q_tables.shape[1] >> bits, top_k=top_k)
@@ -338,10 +338,10 @@ def fused_scored_topk_masked(q_words, q_tables, words_db, valid_words,
     kw = _tuned("fused_scored_topk_masked", q_tables.dtype, block_kwargs,
                 q=q_words.shape[0], n=words_db.shape[0],
                 w=q_words.shape[1], t=q_tables.shape[1], top_k=top_k)
-    vals, ids, chunks = fused_scored_topk_masked_pallas(
+    vals, ids, tallies = fused_scored_topk_masked_pallas(
         q_words, q_tables, words_db, valid_words, bits, k, rerank_m,
-        top_k, scales=scales, interpret=_interpret(), lut_chunks=True,
+        top_k, scales=scales, interpret=_interpret(), counters=True,
         **kw)
-    if lut_chunks is not None:
-        lut_chunks.append(chunks)
+    if counters is not None:
+        counters.append(tallies)
     return vals, ids

@@ -35,7 +35,9 @@ start of the flush slice that answers a ticket
 (``serve.queue_wait_s``), cache hit/miss/eviction/invalidation and
 warmup-compile counters, the 8-row chunks the fused scored kernel
 LUT-scored and all of its chunks (``serve.lut_chunks_scored``,
-``serve.lut_chunks``; fetched with the batch's ids), the bytes a placed
+``serve.lut_chunks``), the histogram bins its first sweep visited and
+all of them (``serve.hist_bins_visited``, ``serve.hist_bins``; all four
+fetched with the batch's ids), the bytes a placed
 engine moved to another chip (``serve.shard_put_bytes`` for the query,
 ``serve.shard_gather_bytes`` for the results; 0 unplaced), and a
 padding-waste gauge. The old ad-hoc
@@ -155,6 +157,8 @@ class AnnService:
         self._c_wait = reg.counter("serve.queue_wait_s")
         self._c_lut_scored = reg.counter("serve.lut_chunks_scored")
         self._c_lut_chunks = reg.counter("serve.lut_chunks")
+        self._c_bins_visited = reg.counter("serve.hist_bins_visited")
+        self._c_bins = reg.counter("serve.hist_bins")
         self._c_shard_put = reg.counter("serve.shard_put_bytes")
         self._c_shard_gather = reg.counter("serve.shard_gather_bytes")
         self._h_flush = reg.histogram("serve.flush_s")
@@ -248,6 +252,8 @@ class AnnService:
             "queue_wait_s": self._c_wait.value,
             "lut_chunks_scored": self._c_lut_scored.value,
             "lut_chunks": self._c_lut_chunks.value,
+            "hist_bins_visited": self._c_bins_visited.value,
+            "hist_bins": self._c_bins.value,
             "shard_put_bytes": self._c_shard_put.value,
             "shard_gather_bytes": self._c_shard_gather.value,
         })
@@ -498,6 +504,7 @@ class AnnService:
                  self._c_batches, self._c_padded, self._c_classified,
                  self._c_flush_err, self._c_classify_err, self._c_wait,
                  self._c_lut_scored, self._c_lut_chunks,
+                 self._c_bins_visited, self._c_bins,
                  self._c_shard_put, self._c_shard_gather,
                  self._g_waste, self.sampler, self.quality)
         eng_quality = getattr(self.engine, "quality", None)
@@ -515,6 +522,8 @@ class AnnService:
         self._c_wait = reg.counter("serve.probe.queue_wait_s")
         self._c_lut_scored = reg.counter("serve.probe.lut_chunks_scored")
         self._c_lut_chunks = reg.counter("serve.probe.lut_chunks")
+        self._c_bins_visited = reg.counter("serve.probe.hist_bins_visited")
+        self._c_bins = reg.counter("serve.probe.hist_bins")
         self._c_shard_put = reg.counter("serve.probe.shard_put_bytes")
         self._c_shard_gather = reg.counter(
             "serve.probe.shard_gather_bytes")
@@ -532,6 +541,7 @@ class AnnService:
              self._c_batches, self._c_padded, self._c_classified,
              self._c_flush_err, self._c_classify_err, self._c_wait,
              self._c_lut_scored, self._c_lut_chunks,
+             self._c_bins_visited, self._c_bins,
              self._c_shard_put, self._c_shard_gather,
              self._g_waste, self.sampler, self.quality) = saved
             if eng_quality is not None:
@@ -656,18 +666,21 @@ class AnnService:
                                       rerank_m=cfg.rerank_m,
                                       fused=cfg.fused,
                                       table_dtype=cfg.table_dtype))
-                chunks = getattr(self.engine, "last_lut_chunks", ())
+                tallies = getattr(self.engine, "last_kernel_counters", ())
                 shard = getattr(self.engine, "last_shard_bytes", (0, 0))
             with span("serve.fetch"):
                 if miss:
                     # host transfer is the device sync for this batch
                     # (np.asarray blocks on the result buffers)
                     ids, rho = np.asarray(sp.sync(ids)), np.asarray(rho)
-                    if chunks:
-                        scored, total = np.sum(
-                            [np.asarray(c) for c in chunks], axis=0).tolist()
+                    if tallies:
+                        scored, chunks, visited, bins = np.sum(
+                            [np.asarray(c) for c in tallies],
+                            axis=0).tolist()
                         self._c_lut_scored.inc(scored)
-                        self._c_lut_chunks.inc(total)
+                        self._c_lut_chunks.inc(chunks)
+                        self._c_bins_visited.inc(visited)
+                        self._c_bins.inc(bins)
                     self._c_shard_put.inc(shard[0])
                     self._c_shard_gather.inc(shard[1])
                     self.flight.record(

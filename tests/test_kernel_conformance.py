@@ -456,14 +456,22 @@ def test_fused_scored_masked_conformance(bits, density, table_dtype):
 ])
 def test_fused_scored_edge_cases(case):
     """Degenerate geometries: overflow slots are (-inf, -1) and the
-    fused and two-stage rankings still agree slot for slot."""
+    fused and two-stage rankings still agree slot for slot; a one-tile
+    corpus walks the histogram from bin 0 to its largest count."""
     q, k, bits = 4, 33, 2
     n, m, top_k = case["n"], case["m"], case["top_k"]
     key = jax.random.PRNGKey(n * m + top_k)
     wq, wdb, tab, _ = _fused_problem(key, q, n, k, bits, "f32")
-    got = fused_scored_topk_pallas(wq, tab, wdb, bits, k, m, top_k,
-                                   block_q=8, block_n=32, interpret=True)
+    *got, tallies = fused_scored_topk_pallas(
+        wq, tab, wdb, bits, k, m, top_k, block_q=8, block_n=32,
+        interpret=True, counters=True)
     want = ref.fused_scored_topk_ref(wq, tab, wdb, bits, k, m, top_k)
+    assert np.asarray(tallies)[2:].tolist() == list(
+        _window_bins(wq, wdb, None, bits, k, m, 8, 32))
+    if n <= 32:
+        padded = jnp.pad(wq, ((0, 4), (0, 0)))      # block_q = 8 lanes
+        walk = int(jnp.max(ref.packed_collision_ref(padded, wdb, bits, k)))
+        assert int(tallies[2]) == walk
     _eq_pairs(got, want)
     _eq_pairs(want, ref.two_stage_scored_ref(wq, tab, wdb, bits, k, m,
                                              top_k))
@@ -520,25 +528,92 @@ def test_fused_scored_gated_regime(masked, table_dtype, n, density):
     key = jax.random.PRNGKey(n + 7 * masked)
     wq, wdb, tab, scales = _fused_problem(key, q, n, k, bits, table_dtype)
     kw = dict(scales=scales, block_q=block_q, block_n=block_n,
-              interpret=True, lut_chunks=True)
+              interpret=True, counters=True)
     if masked:
         flags, vwords = _mask(jax.random.fold_in(key, 9), n, density)
-        *got, chunks = fused_scored_topk_masked_pallas(
+        *got, tallies = fused_scored_topk_masked_pallas(
             wq, tab, wdb, vwords, bits, k, m, top_k, **kw)
         want = ref.fused_scored_topk_masked_ref(wq, tab, wdb, vwords, bits,
                                                 k, m, top_k, scales=scales)
     else:
         flags = None
-        *got, chunks = fused_scored_topk_pallas(wq, tab, wdb, bits, k, m,
-                                                top_k, **kw)
+        *got, tallies = fused_scored_topk_pallas(wq, tab, wdb, bits, k, m,
+                                                 top_k, **kw)
         want = ref.fused_scored_topk_ref(wq, tab, wdb, bits, k, m, top_k,
                                          scales=scales)
     _eq_pairs(got, want, "gated kernel vs fused ref")
     scored, total = _survivor_chunks(wq, wdb, flags, bits, k, m, block_q,
                                      block_n)
-    assert np.asarray(chunks).tolist() == [scored, total]
+    assert np.asarray(tallies)[:2].tolist() == [scored, total]
     # the regime under test: a survivor opens at most one chunk
     assert scored <= q * m < total // 8
+
+
+def _window_bins(wq, wdb, live, bits, k, m, block_q, block_n):
+    """(histogram bins sweep A visits, all of them), replayed from the
+    oracle's counts: per query tile, corpus tile j walks [lo, hi) — hi
+    its largest count over every lane, padded query lanes (zero words)
+    included; lo the least threshold of any real query over tiles
+    0..j-1 (the least c with A(c) < m), 0 before the first."""
+    q, n = wq.shape[0], wdb.shape[0]
+    qp, nt = -(-q // block_q) * block_q, -(-n // block_n)
+    counts = np.asarray(ref.packed_collision_ref(
+        jnp.pad(wq, ((0, qp - q), (0, 0))), wdb, bits, k))
+    if live is not None:
+        counts = np.where(np.asarray(live)[None, :], counts, -1)
+    counts = np.pad(counts, ((0, 0), (0, nt * block_n - n)),
+                    constant_values=-1)
+    visited, bins = 0, np.arange(k + 1)
+    for q0 in range(0, qp, block_q):
+        lo, above = 0, np.zeros((min(q - q0, block_q), k + 1), np.int64)
+        for j in range(nt):
+            tile = counts[q0:q0 + block_q, j * block_n:(j + 1) * block_n]
+            visited += max(int(tile.max()) - lo, 0)
+            above += (tile[:len(above), :, None] > bins).sum(axis=1)
+            lo = int((above < m).argmax(axis=1).min())
+    return visited, qp // block_q * nt * (k + 1)
+
+
+@pytest.mark.parametrize("case", [
+    pytest.param("many-tiles", id="many-tiles"),
+    pytest.param("late-duplicate", id="late-duplicate"),
+    pytest.param("ties", id="ties-straddle-tiles"),
+    pytest.param("tombstones", id="tombstones"),
+])
+def test_fused_scored_bin_window(case):
+    """Sweep A bins only [lo, hi) per tile and stays bit-exact over 40
+    tiles: with the threshold risen, a late planted duplicate of a
+    query (count k) widens one tile's window; a corpus of 24 repeated
+    rows puts threshold ties in many tiles; tombstones count -1. q = 5
+    leaves 3 padded lanes, which must not hold lo down. The bins the
+    kernel counts are the replay's, and far fewer than all."""
+    q, n, k, bits, m, top_k = 5, 40 * 32, 64, 2, 6, 4
+    key = jax.random.PRNGKey(16)
+    wq, wdb, tab, _ = _fused_problem(key, q, n, k, bits, "f32")
+    plain = wdb
+    if case == "late-duplicate":
+        wdb = wdb.at[n - 7].set(wq[0]).at[n - 45].set(wq[2])
+    elif case == "ties":
+        wdb = jnp.resize(wdb[:24], wdb.shape)
+    kw = dict(block_q=8, block_n=32, interpret=True, counters=True)
+    if case == "tombstones":
+        live, vwords = _mask(jax.random.fold_in(key, 9), n, 0.35)
+        *got, tallies = fused_scored_topk_masked_pallas(
+            wq, tab, wdb, vwords, bits, k, m, top_k, **kw)
+        want = ref.fused_scored_topk_masked_ref(wq, tab, wdb, vwords, bits,
+                                                k, m, top_k)
+    else:
+        live = None
+        *got, tallies = fused_scored_topk_pallas(wq, tab, wdb, bits, k, m,
+                                                 top_k, **kw)
+        want = ref.fused_scored_topk_ref(wq, tab, wdb, bits, k, m, top_k)
+    _eq_pairs(got, want, "windowed kernel vs fused ref")
+    visited, bins = _window_bins(wq, wdb, live, bits, k, m, 8, 32)
+    assert np.asarray(tallies)[2:].tolist() == [visited, bins]
+    assert visited < bins // 4
+    if case == "late-duplicate":      # the two planted tiles walk to k
+        assert visited >= _window_bins(wq, plain, None, bits, k, m, 8,
+                                       32)[0] + 2 * (k - 40)
 
 
 @settings(max_examples=8, deadline=None)

@@ -222,13 +222,13 @@ def test_service_scored_mode(scored_world):
                                   np.asarray(ids_direct[0]))
 
 
-def _lut_share_reader():
-    """``read`` of the benchmark's ``kernel.scan_scored_lut_share``."""
+def _metric_reader(name):
+    """``read`` of the benchmark's per-layer metric ``name``."""
     import importlib.util
     import pathlib
     path = (pathlib.Path(__file__).resolve().parents[1] / "chipbench"
-            / "metrics" / "kernel.scan_scored_lut_share.py")
-    spec = importlib.util.spec_from_file_location("lut_share", path)
+            / "metrics" / f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
@@ -236,8 +236,9 @@ def _lut_share_reader():
 
 def test_service_counts_lut_chunks(scored_world):
     """A scored flush through the fused kernel counts the 8-row chunks
-    it LUT-scored and all of them; unscored serving counts neither, and
-    the benchmark's share reads a percentage of the scored flush."""
+    it LUT-scored and all of them, and the histogram bins it visited and
+    all of them; unscored serving counts none, and the benchmark's
+    shares read a percentage of the scored flush."""
     engine, corpus, queries, gt = scored_world
     m = MutableAnnEngine(engine.sketcher, tail_rows=256)
     m.add(corpus)
@@ -251,11 +252,18 @@ def test_service_counts_lut_chunks(scored_world):
         svc.flush()
     s = scored.stats
     assert 0 < s["lut_chunks_scored"] <= s["lut_chunks"]
-    assert plain.stats["lut_chunks_scored"] == 0
-    assert plain.stats["lut_chunks"] == 0
-    share = _lut_share_reader()({"counters": dict(s)})
-    assert 0 < share <= 100
-    assert _lut_share_reader()({"counters": dict(plain.stats)}) is None
+    assert 0 < s["hist_bins_visited"] <= s["hist_bins"]
+    for key in ("lut_chunks_scored", "lut_chunks", "hist_bins_visited",
+                "hist_bins"):
+        assert plain.stats[key] == 0
+    for name in ("kernel.scan_scored_lut_share",
+                 "kernel.scan_scored_hist_share"):
+        read = _metric_reader(name)
+        assert 0 < read({"counters": dict(s)}) <= 100
+        assert read({"counters": dict(plain.stats)}) is None
+    # a program without the bin counters leaves the share unread
+    hist_share = _metric_reader("kernel.scan_scored_hist_share")
+    assert hist_share({"counters": {"lut_chunks": 8}}) is None
 
 
 def test_service_autotune_warmup_both_store_types(scored_world):
